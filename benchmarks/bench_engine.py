@@ -274,8 +274,8 @@ def replica_throughput(n_replicas=8, n_servers=64, n_jobs=400,
 
 
 def shard_point(n_shards, n_servers, n_jobs=600, seed=0):
-    """events/s of the rack-sharded engine on ``n_shards`` devices (this
-    process must already see that many).  Times ``run_sharded`` (plain
+    """events/s of the rack-sharded engine on the first ``n_shards``
+    devices this process sees.  Times ``run_sharded`` (plain
     ``engine.run`` for 1 shard — what a single-device user runs) warm,
     and reports per-device throughput plus the collective count per
     macro-step read off the shard-mapped jaxpr."""
@@ -317,30 +317,22 @@ def shard_point(n_shards, n_servers, n_jobs=600, seed=0):
     return rec
 
 
-def shard_scaling(devices=(1, 2, 8), n_servers=65536, n_jobs=600,
-                  verbose=True):
-    """Devices-{1,2,8} throughput curve for one farm, each point in a
-    fresh subprocess so XLA_FLAGS can pin its virtual CPU device count.
-    On a single-core host the virtual devices timeshare one core, so the
-    curve measures sharding OVERHEAD there, not speedup — the recorded
-    host_cpus field says which regime produced the numbers."""
+def shard_scaling(n_servers=65536, n_jobs=600, verbose=True):
+    """Throughput curve for one farm over 1, 2, 4, 8 devices, as many of
+    them as this process sees, all measured in this process (a child
+    process could not reach a chip the parent already holds).  For
+    virtual CPU devices, launch with
+    XLA_FLAGS=--xla_force_host_platform_device_count=8.  On a single-core
+    host the virtual devices timeshare one core, so the curve measures
+    sharding OVERHEAD there, not speedup — the recorded host_cpus field
+    says which regime produced the numbers."""
     import os
-    import subprocess
-    import sys
     rec = {"n_servers": n_servers, "host_cpus": os.cpu_count() or 1,
            "devices": {}}
-    for k in devices:
-        env = dict(os.environ,
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count={k}")
-        env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_engine",
-             "--shard-point", f"{k},{n_servers},{n_jobs}"],
-            env=env, capture_output=True, text=True, timeout=1800)
-        if r.returncode:
-            raise RuntimeError(f"shard point k={k} failed:\n"
-                               f"{r.stdout}{r.stderr}")
-        point = json.loads(r.stdout.splitlines()[-1])
+    for k in (1, 2, 4, 8):
+        if k > jax.device_count():
+            break
+        point = shard_point(k, n_servers, n_jobs)
         rec["devices"][str(k)] = point
         if verbose:
             cc = point.get("collective_total", 0)
@@ -361,7 +353,11 @@ def shard_scaling(devices=(1, 2, 8), n_servers=65536, n_jobs=600,
 
 
 def run(verbose=True, sizes=(64, 512, 4096, 20480, 65536), smoke=False):
-    out = {"smoke": smoke}
+    dev = jax.devices()[0]
+    # host-clock timings: they are chip speeds only where platform is tpu
+    out = {"smoke": smoke, "device": {"platform": dev.platform,
+                                      "kind": dev.device_kind,
+                                      "count": jax.device_count()}}
     if smoke:
         # the 20480-server point rides in smoke too (ROADMAP scale check:
         # not re-measured since the scatter elimination) — same 600-job
@@ -474,17 +470,7 @@ def main(argv=None):
     ap.add_argument("--check", metavar="COMMITTED.json", default=None,
                     help="fail (exit 1) if any perf.* case drops >30%% "
                          "below the committed record at this path")
-    ap.add_argument("--shard-point", metavar="K,N_SERVERS,N_JOBS",
-                    default=None,
-                    help="internal: measure ONE shard-scaling point in "
-                         "this process (launched by shard_scaling with "
-                         "XLA_FLAGS pinning K virtual devices) and print "
-                         "its JSON record")
     args = ap.parse_args(argv)
-    if args.shard_point:
-        k, n_servers, n_jobs = map(int, args.shard_point.split(","))
-        print(json.dumps(shard_point(k, n_servers, n_jobs)))
-        return None
     out = run(smoke=args.smoke)
     with open(args.out, "w") as f:
         json.dump(out, f, indent=1)

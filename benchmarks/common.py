@@ -8,10 +8,13 @@ import numpy as np
 
 sys.path.insert(0, "src")  # allow `python -m benchmarks.run` from repo root
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import farm as farm_mod          # noqa: E402
 from repro.core import workload                  # noqa: E402
 from repro.core.jobs import dag_single           # noqa: E402
 from repro.core.types import SimConfig           # noqa: E402
+
+enable_compile_cache()
 
 # paper workload models (§IV-B): web search ~5ms, web serving ~120ms
 WEB_SEARCH_SVC = 0.005

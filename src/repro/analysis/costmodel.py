@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+from jax.extend import core as jax_core
 import numpy as np
 
 from .jaxpr_audit import iter_eqns
@@ -108,17 +109,17 @@ def eqn_cost(eqn) -> tuple:
     the walker visits their eqns separately)."""
     name = eqn.primitive.name
     has_sub = any(
-        isinstance(p, (jax.core.ClosedJaxpr, jax.core.Jaxpr))
+        isinstance(p, (jax_core.ClosedJaxpr, jax_core.Jaxpr))
         or (
             isinstance(p, (tuple, list))
-            and any(isinstance(q, (jax.core.ClosedJaxpr, jax.core.Jaxpr)) for q in p)
+            and any(isinstance(q, (jax_core.ClosedJaxpr, jax_core.Jaxpr)) for q in p)
         )
         for p in eqn.params.values()
     )
     if has_sub:
         return 0, 0  # charged to the inner eqns
     bytes_ = sum(
-        _aval_bytes(v.aval) for v in eqn.invars if not isinstance(v, jax.core.Literal)
+        _aval_bytes(v.aval) for v in eqn.invars if not isinstance(v, jax_core.Literal)
     ) + sum(_aval_bytes(v.aval) for v in eqn.outvars)
     if name in _ZERO_FLOP:
         return bytes_, 0
@@ -133,7 +134,7 @@ def eqn_cost(eqn) -> tuple:
         insz = sum(
             int(np.prod(v.aval.shape))
             for v in eqn.invars
-            if hasattr(v.aval, "shape") and not isinstance(v, jax.core.Literal)
+            if hasattr(v.aval, "shape") and not isinstance(v, jax_core.Literal)
         )
         return bytes_, insz
     if name == "sort":

@@ -27,6 +27,7 @@ import dataclasses
 from typing import Iterator, Optional, Sequence
 
 import jax
+from jax.extend import core as jax_core
 import numpy as np
 
 # Region tag marking intentional exits from the time domain: values
@@ -45,6 +46,7 @@ COLLECTIVE_PRIMS = frozenset(
         "all_gather_invariant",
         "psum",
         "psum2",
+        "psum_invariant",
         "pmin",
         "pmax",
         "all_to_all",
@@ -83,9 +85,9 @@ def _source_of(eqn) -> str:
 
 def _sub_jaxprs(value) -> Iterator:
     """Yield every (open) jaxpr buried in an eqn param value."""
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jax_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jax_core.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for item in value:
@@ -234,7 +236,7 @@ class _TaintEnv:
         self._env: dict = {}
 
     def read(self, var) -> tuple:
-        if isinstance(var, jax.core.Literal):
+        if isinstance(var, jax_core.Literal):
             return _NO_TAINT
         return self._env.get(var, _NO_TAINT)
 

@@ -202,12 +202,11 @@ def _advance_interval(state: SimState, cfg: SimConfig, tc, t_next):
                 "cfg.use_kernel requires time_dtype=float32: the fused "
                 "advance kernel computes in f32, and the core_busy_until "
                 "round-trip would silently destroy f64 precision")
-        from ..kernels import dcsim_step
+        from ..kernels import dcsim_step, interpret_mode
         sp = cfg.server_power
         table = jnp.asarray([sp.p_base, sp.p_base, sp.p_pkg_c6, sp.p_s3,
                              sp.p_off, sp.p_wake], jnp.float32)
         thr = throttled if cfg.thermal.throttling else None
-        interp = jax.default_backend() != "tpu"
         nb, _done, en, bs, _cand = dcsim_step.dcsim_advance(
             farm.core_busy_until.astype(jnp.float32), farm.srv_state,
             farm.energy, farm.busy_core_seconds, state.t, t_next, table,
@@ -216,7 +215,7 @@ def _advance_interval(state: SimState, cfg: SimConfig, tc, t_next):
             farm.srv_idle_since.astype(jnp.float32),
             farm.srv_tau.astype(jnp.float32), throttled=thr,
             throttle_power_scale=cfg.thermal.throttle_power_scale,
-            interpret=interp)
+            interpret=interpret_mode())
         farm = replace(farm,
                        core_busy_until=nb.astype(cfg.time_dtype),
                        energy=en, busy_core_seconds=bs,

@@ -57,11 +57,10 @@ def run_replicas(cfg: SimConfig, state_b, tc=None, mesh=None):
         return jax.jit(runner)(state_b)
     from jax.sharding import PartitionSpec as P
 
-    from ..sharding.compat import shard_map
     bax = tuple(a for a in mesh.axis_names if a != cfg.partition.axis)
     # prefix spec: replica dim 0 over the non-rack axes
     spec = P(bax) if bax else P()
-    fn = shard_map(runner, mesh=mesh, in_specs=(spec,), out_specs=spec,
+    fn = jax.shard_map(runner, mesh=mesh, in_specs=(spec,), out_specs=spec,
                    check_vma=False)
     return jax.jit(fn)(state_b)
 

@@ -38,7 +38,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..sharding import partition as mesh_lib
-from ..sharding.compat import shard_map
 from . import engine
 from .types import SimConfig
 
@@ -152,7 +151,7 @@ def _runner_for(cfg: SimConfig, mesh, axis, treedef, specs, n_state):
         return jax.lax.while_loop(cond_lv, body,
                                   tuple(all_leaves[:n_state]))
 
-    fn = shard_map(loop, mesh=mesh, in_specs=specs,
+    fn = jax.shard_map(loop, mesh=mesh, in_specs=specs,
                    out_specs=state_specs, check_vma=False)
     return jax.jit(fn)
 
@@ -191,7 +190,7 @@ def sharded_step_jaxpr(state, cfg: SimConfig, tc=None, mesh=None):
     specs = mesh_lib.sim_state_specs(state, cfg, mesh, axis)
     leaves, treedef = jax.tree.flatten(state)
     step = _sharded_step_fn(cfg, tc, specs, treedef, axis, n_shards)
-    fn = shard_map(step, mesh=mesh, in_specs=specs, out_specs=specs,
+    fn = jax.shard_map(step, mesh=mesh, in_specs=specs, out_specs=specs,
                    check_vma=False)
     return jax.make_jaxpr(fn)(*leaves)
 

@@ -21,7 +21,8 @@ device, entirely inside ``lax.while_loop``:
 The hot accumulation path has two interchangeable backends: the fused Pallas
 kernel (``kernels/telemetry_bin.py`` — histogram binning + window bucketing
 in one VMEM pass) and its pure-jnp oracle (``kernels/ref.py``), selected by
-``TelemetryConfig.use_kernel``.  Off-TPU the kernel runs in interpret mode.
+``TelemetryConfig.use_kernel``.  On CPU the kernel runs in interpret mode
+(``kernels.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -233,8 +234,7 @@ def accumulate_finishes(telem: Telemetry, cfg: SimConfig, jobs,
 
         from ..kernels import ref
         if tcfg.use_kernel:
-            from ..kernels import telemetry_bin
-            interp = jax.default_backend() != "tpu"
+            from ..kernels import interpret_mode, telemetry_bin
             # the fused kernel bins histograms and buckets windows in one
             # pass; windows accrue separately per interval now, so feed
             # it a single dummy row with a zero add (the kernel shapes
@@ -243,7 +243,7 @@ def accumulate_finishes(telem: Telemetry, cfg: SimConfig, jobs,
             jh, th, _ = telemetry_bin.telemetry_accum(
                 job_lat, jw, task_lat, tw, jh0, th0, telem.win[:1],
                 jnp.zeros((), jnp.int32), zwin,
-                tcfg.lat_lo, tcfg.lat_hi, interpret=interp)
+                tcfg.lat_lo, tcfg.lat_hi, interpret=interpret_mode())
             return jh, th, miss, tot, tail
 
         def dense(args):

@@ -171,7 +171,9 @@ def _rack_sums(therm: ThermalState, vals):
     R = therm.rack_inv.shape[0]
     if therm.rack_onehot.size == 0:                # contiguous fast path
         return vals.reshape(R, -1).sum(axis=1)
-    return therm.rack_onehot @ vals
+    # full f32: the TPU's default matmul precision rounds through bf16
+    return jnp.matmul(therm.rack_onehot, vals,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def inlet_temps(therm: ThermalState, tcfg: ThermalConfig,

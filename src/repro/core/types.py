@@ -338,7 +338,7 @@ class TelemetryConfig:
     # per-job deadlines come from JobTable.sla
     tail_thresh: float = 1.0
     # route the hot accumulation through the fused Pallas kernel
-    # (kernels/telemetry_bin.py); off-TPU it falls back to interpret mode
+    # (kernels/telemetry_bin.py); interpreted on CPU, compiled on TPU
     use_kernel: bool = False
     # compact the "new finishes" set into a batch of this size before
     # histogram binning when few jobs/tasks finished this step (the jnp
@@ -428,8 +428,8 @@ class SimConfig:
     use_vectorized_hot_loop: bool = True
     # route the interval advance (energy accrual + completion free + farm
     # next-event candidate) through the fused Pallas kernel
-    # (kernels/dcsim_step.py); off-TPU it falls back to interpret mode,
-    # mirroring the telemetry backend switch
+    # (kernels/dcsim_step.py); interpreted on CPU, compiled on TPU, like
+    # the telemetry kernel (kernels.interpret_mode)
     use_kernel: bool = False
     # policies
     sched_policy: int = SchedPolicy.LOAD_BALANCE

@@ -1,5 +1,5 @@
-"""Pallas TPU kernels for the perf-critical hot spots, each with a jitted
-wrapper (ops.py) and a pure-jnp oracle (ref.py):
+"""Pallas TPU kernels for the perf-critical hot spots, each with a pure-jnp
+oracle (ref.py):
 
   flash_attention  prefill/train attention (causal, sliding-window,
                    softcap, GQA) — streaming softmax, VMEM-resident scores
@@ -10,4 +10,20 @@ wrapper (ops.py) and a pure-jnp oracle (ref.py):
   telemetry_bin    fused telemetry accumulation (latency-histogram binning
                    + time-series window bucketing in one VMEM pass)
 """
-from . import ops, ref, telemetry_bin  # noqa: F401
+import jax
+
+from . import ref, telemetry_bin  # noqa: F401
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode on this backend: yes on
+    ``cpu`` (where the tests run), no on ``tpu`` (compiled by Mosaic).  Any
+    other backend raises rather than quietly interpreting."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on tpu or interpreted on cpu; the "
+        f"{backend!r} backend is neither")

@@ -5,7 +5,7 @@ from HBM several times per event: once for the min-reduction, once for
 energy accrual, once for the completion update.  This kernel fuses the
 "advance farm to t_next" into a single VMEM pass over server blocks:
 
-  per server block (block_n, C):
+  per block of servers (servers over sublanes x lanes, one plane per core):
     busy count -> piecewise power -> energy += P·dt, busy_seconds += busy·dt
     completions (busy_until <= t_next) freed to INF, mask emitted
     next-event candidate: min over the block of surviving busy_until,
@@ -26,21 +26,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 INF = 1.0e30
+LANES = 128
 
 
-def _kernel(t_ref, tn_ref, busy_ref, state_ref, energy_ref, bsec_ref,
-            wake_ref, isince_ref, tau_ref, thr_ref, ptab_ref,
+def _kernel(time_ref, ptab_ref, busy_ref, state_ref, energy_ref, bsec_ref,
+            wake_ref, isince_ref, tau_ref, thr_ref,
             new_busy_ref, done_ref, new_energy_ref, new_bsec_ref, next_ref,
             *, p_core_active, p_core_idle, n_cores, throttle_power_scale):
-    dt = (tn_ref[0] - t_ref[0]).astype(jnp.float32)
-    cb = busy_ref[...]                                    # (bn, C)
-    st = state_ref[...]                                   # (bn,)
-    busy = (cb < INF).astype(jnp.float32).sum(axis=1)     # (bn,)
+    t_next = time_ref[1]
+    dt = t_next - time_ref[0]
+    cb = busy_ref[...]                                    # (C, br, 128)
+    st = state_ref[...]                                   # (br, 128)
+    busy = (cb < INF).astype(jnp.float32).sum(axis=0)     # (br, 128)
     awake = st <= 1
     # thermally throttled servers draw scaled active-core power
     # (linear-DVFS approximation, mirrors power.server_power)
@@ -49,33 +50,42 @@ def _kernel(t_ref, tn_ref, busy_ref, state_ref, energy_ref, bsec_ref,
                       jnp.float32(p_core_active))
     p_awake = ptab_ref[0] + busy * p_act \
         + (n_cores - busy) * p_core_idle
-    p_state = ptab_ref[jnp.clip(st, 0, ptab_ref.shape[0] - 1)]
+    # table[clip(state)] as a select chain over the SMEM table: the chip
+    # has no vector gather from a table
+    p_state = jnp.full(st.shape, ptab_ref[0], jnp.float32)
+    for s in range(1, ptab_ref.shape[0]):
+        p_state = jnp.where(st >= s, ptab_ref[s], p_state)
     p = jnp.where(awake, p_awake, p_state)
     new_energy_ref[...] = energy_ref[...] + p * dt
     new_bsec_ref[...] = bsec_ref[...] + busy * dt
-    done = cb <= tn_ref[0]
-    done_ref[...] = done.astype(jnp.int8)
+    done = cb <= t_next
+    done_ref[...] = done.astype(jnp.int32)
     new_busy = jnp.where(done, INF, cb)
     new_busy_ref[...] = new_busy
     # farm candidates for the next event: surviving completions, pending
     # wakeups, and delay-timer expiries of IDLE (state==1) servers
     timer = jnp.where(st == 1, isince_ref[...] + tau_ref[...], INF)
-    cand = jnp.minimum(new_busy.min(axis=1),
+    cand = jnp.minimum(new_busy.min(axis=0),
                        jnp.minimum(wake_ref[...], timer))
-    next_ref[0] = cand.min()
+    next_ref[...] = cand.min(axis=0, keepdims=True)[None]
 
 
 def dcsim_advance(core_busy, srv_state, energy, busy_seconds, t, t_next,
                   state_power, p_core_active, p_core_idle,
                   srv_wake_at=None, srv_idle_since=None, srv_tau=None,
                   throttled=None, *, throttle_power_scale=1.0,
-                  block_n=256, interpret=False):
+                  block_n=4096, interpret=False):
     """Fused farm advance.  core_busy (N, C) f32; srv_state (N,) int32;
     energy/busy_seconds/srv_wake_at/srv_idle_since/srv_tau (N,) f32;
     t/t_next scalars; state_power (SrvState.NUM,) f32 table (index 0 =
     base power of an awake server); throttled (N,) bool/int —
     thermally-throttled servers accrue active-core power scaled by
     ``throttle_power_scale`` (the PR 3 linear-DVFS coupling).
+
+    Inside the kernel servers run along the 128 lanes: per-server vectors
+    are laid out (rows, 128) and core_busy (C, rows, 128), cut into blocks
+    of ``block_n`` servers (rounded to whole 8-row tiles).  t/t_next and
+    the power table sit in SMEM.
 
     Returns (new_core_busy, done_mask (N, C) bool, energy, busy_seconds,
     next_candidate) where next_candidate is the farm's min next-event time
@@ -90,61 +100,55 @@ def dcsim_advance(core_busy, srv_state, energy, busy_seconds, t, t_next,
         srv_tau = jnp.full((N,), INF, jnp.float32)
     if throttled is None:
         throttled = jnp.zeros((N,), jnp.int32)
-    throttled = throttled.astype(jnp.int32)
-    block_n = min(block_n, N)
-    pad = (-N) % block_n
-    if pad:
-        core_busy = jnp.pad(core_busy, ((0, pad), (0, 0)),
-                            constant_values=INF)
-        srv_state = jnp.pad(srv_state, (0, pad), constant_values=4)  # OFF
-        energy = jnp.pad(energy, (0, pad))
-        busy_seconds = jnp.pad(busy_seconds, (0, pad))
-        srv_wake_at = jnp.pad(srv_wake_at, (0, pad), constant_values=INF)
-        srv_idle_since = jnp.pad(srv_idle_since, (0, pad))
-        srv_tau = jnp.pad(srv_tau, (0, pad), constant_values=INF)
-        throttled = jnp.pad(throttled, (0, pad))
-    Np = N + pad
-    grid = (Np // block_n,)
+    rows = pl.cdiv(N, LANES)
+    br = max(8, block_n // LANES // 8 * 8)
+    if rows <= br:
+        br = rows                     # one block spanning the whole farm
+    else:
+        rows = pl.cdiv(rows, br) * br
+    pad = rows * LANES - N
+
+    def lanes(x, fill, dtype=jnp.float32):
+        return jnp.pad(x.astype(dtype), (0, pad),
+                       constant_values=fill).reshape(rows, LANES)
+
+    busy_l = jnp.pad(core_busy.astype(jnp.float32), ((0, pad), (0, 0)),
+                     constant_values=INF).T.reshape(C, rows, LANES)
+    vecs = (lanes(srv_state, 4, jnp.int32),                 # pad: OFF
+            lanes(energy, 0), lanes(busy_seconds, 0),
+            lanes(srv_wake_at, INF), lanes(srv_idle_since, 0),
+            lanes(srv_tau, INF), lanes(throttled, 0, jnp.int32))
+    times = jnp.stack([jnp.asarray(t, jnp.float32),
+                       jnp.asarray(t_next, jnp.float32)])
+    n_blocks = rows // br
 
     kernel = functools.partial(_kernel, p_core_active=p_core_active,
                                p_core_idle=p_core_idle, n_cores=C,
                                throttle_power_scale=throttle_power_scale)
-    t1 = jnp.asarray(t, jnp.float32).reshape(1)
-    t2 = jnp.asarray(t_next, jnp.float32).reshape(1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    cores = pl.BlockSpec((C, br, LANES), lambda i: (0, i, 0))
+    row = pl.BlockSpec((br, LANES), lambda i: (i, 0))
 
     nb, dm, en, bs, nc = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),                    # t
-            pl.BlockSpec((1,), lambda i: (0,)),                    # t_next
-            pl.BlockSpec((block_n, C), lambda i: (i, 0)),          # busy
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # state
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # energy
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # bsec
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # wake_at
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # idle_since
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # tau
-            pl.BlockSpec((block_n,), lambda i: (i,)),              # throttled
-            pl.BlockSpec((state_power.shape[0],), lambda i: (0,)),  # table
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, C), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, C), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),                    # next cand
-        ],
+        grid=(n_blocks,),
+        in_specs=[smem, smem, cores] + [row] * len(vecs),
+        out_specs=[cores, cores, row, row,
+                   pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((Np, C), core_busy.dtype),
-            jax.ShapeDtypeStruct((Np, C), jnp.int8),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-            jax.ShapeDtypeStruct((Np // block_n,), jnp.float32),
+            jax.ShapeDtypeStruct((C, rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((C, rows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(t1, t2, core_busy, srv_state, energy, busy_seconds,
-      srv_wake_at, srv_idle_since, srv_tau, throttled, state_power)
-    return (nb[:N], dm[:N].astype(bool), en[:N], bs[:N], nc.min())
+    )(times, state_power.astype(jnp.float32), busy_l, *vecs)
+
+    def servers(x):
+        return x.reshape(x.shape[:-2] + (rows * LANES,))[..., :N]
+
+    return (servers(nb).T.astype(core_busy.dtype), servers(dm).T != 0,
+            servers(en), servers(bs), nc.min())

@@ -22,9 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 
 def _kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, y_ref, h_ref, *, chunk_t):
@@ -78,7 +77,7 @@ def ssm_scan(dt, Bm, Cm, x, A, *, block_d=256, chunk_t=16, interpret=False):
                                lambda b, d, t: (b, t, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, Dss), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, Bm, Cm, x, A)

@@ -17,10 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
-from .compat import CompilerParams
 
 
 def _kernel(widx_ref, wvals_ref, jv_ref, jw_ref, tv_ref, tw_ref,
@@ -103,7 +102,7 @@ def telemetry_accum(job_vals, job_wts, task_vals, task_wts,
             jax.ShapeDtypeStruct((B,), jnp.float32),
             jax.ShapeDtypeStruct((W, K), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(widx1, wvals.astype(jnp.float32), jv, jw, tv, tw,

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
-from repro.sharding import compat as mesh_compat
 from repro.models import transformer
 from repro.models.config import SHAPES, ModelConfig, supported_shapes
 from repro.roofline import analysis
@@ -112,7 +111,7 @@ def _lower_one(cfg, shp, mesh):
         batch = {k: v for k, v in ins.items() if k != "modality_mask"}
         jfn = jax.jit(fn, in_shardings=(state_sh, batch_sh),
                       out_shardings=None, donate_argnums=(0,))
-        with mesh_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jfn.lower(state_shapes, batch)
     cshapes, csh = cache_specs(cfg, shp.name, mesh)
     logit_sh = NamedSharding(
@@ -126,13 +125,13 @@ def _lower_one(cfg, shp, mesh):
             out_shardings=(logit_sh, csh), donate_argnums=(2,))
         args = (state_shapes["params"], ins["tokens"], cshapes) \
             + ((frames,) if frames is not None else ())
-        with mesh_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jfn.lower(*args)
     fn = step_lib.make_serve_step(cfg, mesh)
     jfn = jax.jit(fn, in_shardings=(state_sh["params"], csh,
                                     ins["token"].sharding, None),
                   out_shardings=(logit_sh, csh), donate_argnums=(1,))
-    with mesh_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jfn.lower(state_shapes["params"], cshapes, ins["token"],
                          jax.ShapeDtypeStruct((), jnp.int32))
 

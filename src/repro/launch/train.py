@@ -30,7 +30,7 @@ from repro.ckpt.checkpoint import Checkpointer
 from repro.core.montecarlo import young_daly_interval
 from repro.data.pipeline import DataConfig, get_batch
 from repro.launch.mesh import make_local_mesh
-from repro.sharding import compat as mesh_compat, partition
+from repro.sharding import partition
 from repro.train import optim, step as step_lib
 
 
@@ -95,7 +95,7 @@ def main(argv=None):
 
     jit_step = jax.jit(train_step, donate_argnums=(0,))
     losses = []
-    ctx = mesh_compat.set_mesh(mesh) if multi else None
+    ctx = jax.set_mesh(mesh) if multi else None
     if ctx:
         ctx.__enter__()
     try:

@@ -160,8 +160,7 @@ def moe_scatter(p, x, cfg, mesh=None, mesh_axes=("data", "model")):
             out = _combine_local(eo, ti, po, ke, ga, e_base, E_loc, S)
             return jax.lax.psum(out, model_ax)
 
-        from ..sharding.compat import shard_map
-        out = shard_map(
+        out = jax.shard_map(
             combine, mesh=mesh,
             in_specs=(P(bspec, model_ax, None, None), P(bspec), P(bspec),
                       P(bspec), P(bspec)),

@@ -16,7 +16,6 @@ from repro.analysis import jaxpr_audit, retrace, rules
 from repro.analysis.jaxpr_audit import (
     CALLBACK_PRIMS, COLLECTIVE_PRIMS, SCATTER_PRIMS, audit, clock_audit)
 from repro.core.types import pytree_dataclass
-from repro.sharding.compat import shard_map
 
 THIS_FILE = "test_analysis.py"
 
@@ -59,7 +58,7 @@ def test_planted_stray_psum():
     def step(x):
         return jax.lax.psum(x, "racks")
 
-    fn = shard_map(step, mesh=mesh, in_specs=P("racks"), out_specs=P())
+    fn = jax.shard_map(step, mesh=mesh, in_specs=P("racks"), out_specs=P())
     jx = jax.make_jaxpr(fn)(jnp.zeros(4))
     inv = audit(jx)
     rule = rules.ForbidPrimitive(

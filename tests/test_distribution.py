@@ -197,8 +197,7 @@ ref = jax.jit(lambda p, t: transformer.forward(cfg, p, t, mode="train")[0])(para
 psh = partition.tree_shardings(specs, params, mesh)
 params_s = jax.device_put(params, psh)
 tok_s = jax.device_put(tokens, NamedSharding(mesh, P("data")))
-from repro.sharding import compat as mesh_compat
-with mesh_compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     out = jax.jit(lambda p, t: transformer.forward(
         cfg, p, t, mode="train", mesh=mesh)[0])(params_s, tok_s)
 err = np.abs(np.float32(ref) - np.float32(out)).max()

@@ -93,7 +93,7 @@ def test_ssm_scan_matches_ref(B, S, Dss, N, block_d, chunk_t, dtype):
 # dcsim advance
 # --------------------------------------------------------------------------
 
-def _check_dcsim_advance(n, c, seed):
+def _check_dcsim_advance(n, c, seed, block_n=4096):
     rng = np.random.default_rng(seed)
     t = np.float32(rng.uniform(0, 10))
     t_next = np.float32(t + rng.uniform(0, 1))
@@ -118,7 +118,8 @@ def _check_dcsim_advance(n, c, seed):
                         t, t_next, ptab, 13.0, 2.0,
                         jnp.asarray(wake), jnp.asarray(isince),
                         jnp.asarray(tau), jnp.asarray(throttled),
-                        throttle_power_scale=scale, interpret=True)
+                        throttle_power_scale=scale, block_n=block_n,
+                        interpret=True)
     exp = ref.dcsim_advance_reference(
         jnp.asarray(busy), jnp.asarray(state), jnp.asarray(energy),
         jnp.asarray(bsec), jnp.asarray(t), jnp.asarray(t_next), ptab,
@@ -142,6 +143,11 @@ else:
     ])
     def test_dcsim_advance_matches_ref(n, c, seed):
         _check_dcsim_advance(n, c, seed)
+
+
+def test_dcsim_advance_multi_block():
+    """A farm cut into several lane-dense blocks, the last one padded."""
+    _check_dcsim_advance(2500, 4, 3, block_n=1024)
 
 
 # --------------------------------------------------------------------------
