@@ -37,6 +37,23 @@ changes.  Latency binning is deferred to once per macro-step (the finish
 arrays identify every completion since the macro began); window accrual
 stays exact per interval.
 
+Engine regions: each phase of a step runs under a ``jax.named_scope``,
+nested inside ``cheap_core``/``full_step``, and the name reaches the
+compiled program's ``op_name`` metadata, so a profile's device time can be
+read per region (``bench/regions.py``); the innermost scope names an op:
+  next_event    the cheap gate, next_event_time, the done check
+  advance       the interval advance (energy, residency)
+  completions   wakeups, task completions, flow completions
+  admission     deferred releases, arrivals
+  drain_start   drain into server queues, task starts
+  power         idle refresh, provisioning, WASP, delay timers
+  flows         rate recompute and switch states; the flow drain and
+                switch energy inside the advance
+  thermal       throttle latch, setpoint tick; the RC integrator and
+                cooling power inside the advance
+  trace_flush   the flight recorder's ring write
+  telemetry     latency binning; the window accrual inside the advance
+
 Scheduling/assignment model: the global scheduler assigns servers to ALL
 tasks of a job at arrival (policy-driven).  When a parent task finishes,
 each DAG edge either decrements the child's dep_count immediately (no
@@ -169,32 +186,34 @@ def _advance_interval(state: SimState, cfg: SimConfig, tc, t_next):
         # one RC evaluation (recirculated inlet + exponential) and one
         # CRAC/COP evaluation shared by the telemetry columns and the
         # thermal integrator
-        tcfg = cfg.thermal
-        target = p_busy[0] * tcfg.r_th \
-            + thermal_mod.inlet_temps(state.thermal, tcfg, state.t)
-        alpha = 1.0 - jnp.exp(-dtf / tcfg.tau_th)
-        t_end = state.thermal.t_srv \
-            + (target - state.thermal.t_srv) * alpha
-        p_sw_t = power.switch_power(state.net, cfg).sum() \
-            if cfg.has_network else jnp.float32(0.0)
-        p_cool = thermal_mod.cooling_power(p_busy[0], p_sw_t,
-                                           state.thermal, tcfg)
-        thermal_ctx = (target, alpha, t_end, p_cool)
+        with jax.named_scope("thermal"):
+            tcfg = cfg.thermal
+            target = p_busy[0] * tcfg.r_th \
+                + thermal_mod.inlet_temps(state.thermal, tcfg, state.t)
+            alpha = 1.0 - jnp.exp(-dtf / tcfg.tau_th)
+            t_end = state.thermal.t_srv \
+                + (target - state.thermal.t_srv) * alpha
+            p_sw_t = power.switch_power(state.net, cfg).sum() \
+                if cfg.has_network else jnp.float32(0.0)
+            p_cool = thermal_mod.cooling_power(p_busy[0], p_sw_t,
+                                               state.thermal, tcfg)
+            thermal_ctx = (target, alpha, t_end, p_cool)
 
     telem = state.telem
     if telemetry_on:
         # window metrics integrate the PRE-advance state over [t, t_next)
         # (piecewise constant, same exactness as the energy accrual)
-        wvals = telemetry.window_values(state, cfg, dt, p_busy, onehot,
-                                        thermal_ctx)
-        widx = telemetry.window_index(state.t, dt, cfg.telemetry)
-        # intervals past the window horizon still clamp into the last
-        # window (conservation: columns keep integrating to the run
-        # totals) but the clamped seconds are counted so summarize can
-        # flag/NaN the contaminated last-window time-averages
-        spill = telemetry.window_spill(state.t, dt, cfg.telemetry)
-        telem = replace(telem, win=telem.win.at[widx].add(wvals),
-                        win_overflow=telem.win_overflow + spill)
+        with jax.named_scope("telemetry"):
+            wvals = telemetry.window_values(state, cfg, dt, p_busy, onehot,
+                                            thermal_ctx)
+            widx = telemetry.window_index(state.t, dt, cfg.telemetry)
+            # intervals past the window horizon still clamp into the last
+            # window (conservation: columns keep integrating to the run
+            # totals) but the clamped seconds are counted so summarize
+            # can flag/NaN the contaminated last-window time-averages
+            spill = telemetry.window_spill(state.t, dt, cfg.telemetry)
+            telem = replace(telem, win=telem.win.at[widx].add(wvals),
+                            win_overflow=telem.win_overflow + spill)
 
     if cfg.use_kernel:
         if cfg.time_dtype != jnp.float32:
@@ -225,15 +244,17 @@ def _advance_interval(state: SimState, cfg: SimConfig, tc, t_next):
 
     net, flows = state.net, state.flows
     if cfg.has_network:
-        net = power.accrue_switch_energy(net, cfg, dt)
-        # drain the fluid model over the interval (rates are piecewise
-        # constant, fixed at the last recompute)
-        flows = net_mod.advance_flows(flows, dt)
+        with jax.named_scope("flows"):
+            net = power.accrue_switch_energy(net, cfg, dt)
+            # drain the fluid model over the interval (rates are
+            # piecewise constant, fixed at the last recompute)
+            flows = net_mod.advance_flows(flows, dt)
     therm = state.thermal
     if thermal_on:
-        therm = thermal_mod.advance(therm, cfg, p_busy[0], p_sw_t,
-                                    state.t, dt, t_new=t_end,
-                                    p_cool=p_cool)
+        with jax.named_scope("thermal"):
+            therm = thermal_mod.advance(therm, cfg, p_busy[0], p_sw_t,
+                                        state.t, dt, t_new=t_end,
+                                        p_cool=p_cool)
     return replace(state, farm=farm, net=net, flows=flows, thermal=therm,
                    telem=telem, t=t_next)
 
@@ -896,75 +917,85 @@ def _apply_events(state: SimState, cfg: SimConfig, tc, cheap: bool,
     # of the flush lane space out of the hot loop
     trace_sleep = (cfg.trace.enabled
                    and cfg.sleep_policy != SleepPolicy.ALWAYS_ON)
-    if trace_sleep:
-        N = cfg.n_servers
-        woke = (state.farm.srv_state == SrvState.WAKING) \
-            & (state.farm.srv_wake_at <= state.t)
-        trace_mod.stage(recs, woke, TraceKind.WAKEUP,
-                        jnp.arange(N, dtype=jnp.int32))
-    state = replace(state, farm=_apply_wakeups(state.farm, cfg, state.t))
-    state = _apply_completions(state, cfg, tc, recs)
-    if cfg.has_network and not cheap:
-        state = _apply_flow_completions(state, cfg, recs)
-    hold = None
-    if _deferral_on(cfg):
-        # deferred releases admit BEFORE fresh arrivals (lower job ids
-        # drain first; see _apply_releases); a step that entered with due
-        # releases also HOLDS fresh arrivals until the next same-time
-        # step, so the arrival's load snapshot sees the release train
-        # fully admitted AND drained (the oracle's event order)
-        admit_at = state.jobs.admit_at
-        hold = ((admit_at < INF / 2) & (admit_at <= state.t)).any()
-        state = _apply_releases(state, cfg, tc, recs)
-    state = _apply_arrival(state, cfg, tc, hold, recs)
-    state = _drain_ready(state, cfg, recs)
-    state = _start_tasks(state, cfg, recs)
+    with jax.named_scope("completions"):
+        if trace_sleep:
+            N = cfg.n_servers
+            woke = (state.farm.srv_state == SrvState.WAKING) \
+                & (state.farm.srv_wake_at <= state.t)
+            trace_mod.stage(recs, woke, TraceKind.WAKEUP,
+                            jnp.arange(N, dtype=jnp.int32))
+        state = replace(state,
+                        farm=_apply_wakeups(state.farm, cfg, state.t))
+        state = _apply_completions(state, cfg, tc, recs)
+        if cfg.has_network and not cheap:
+            state = _apply_flow_completions(state, cfg, recs)
+    with jax.named_scope("admission"):
+        hold = None
+        if _deferral_on(cfg):
+            # deferred releases admit BEFORE fresh arrivals (lower job ids
+            # drain first; see _apply_releases); a step that entered with
+            # due releases also HOLDS fresh arrivals until the next
+            # same-time step, so the arrival's load snapshot sees the
+            # release train fully admitted AND drained (the oracle's
+            # event order)
+            admit_at = state.jobs.admit_at
+            hold = ((admit_at < INF / 2) & (admit_at <= state.t)).any()
+            state = _apply_releases(state, cfg, tc, recs)
+        state = _apply_arrival(state, cfg, tc, hold, recs)
+    with jax.named_scope("drain_start"):
+        state = _drain_ready(state, cfg, recs)
+        state = _start_tasks(state, cfg, recs)
 
     # refresh ACTIVE/IDLE, run local power controllers + pool managers
-    if trace_sleep:
-        st_before = state.farm.srv_state
-    farm = server.refresh_idle_state(state.farm, cfg, state.t)
-    active = _active_jobs(state.jobs)
-    farm, sched = scheduler.provisioning_adjust(farm, cfg, state.sched,
-                                                active)
-    farm = scheduler.wasp_adjust(farm, cfg, _pending_jobs(state.jobs),
-                                 state.t)
-    farm = scheduler.timer_transitions(farm, cfg, state.t)
-    state = replace(state, farm=farm, sched=sched)
-    if trace_sleep:
-        # awake -> sleep edges from the local power controllers
-        was_awake = (st_before == SrvState.ACTIVE) \
-            | (st_before == SrvState.IDLE)
-        asleep = (farm.srv_state == SrvState.PKG_C6) \
-            | (farm.srv_state == SrvState.S3) \
-            | (farm.srv_state == SrvState.OFF)
-        trace_mod.stage(recs, was_awake & asleep, TraceKind.SLEEP,
-                        jnp.arange(cfg.n_servers, dtype=jnp.int32), -1,
-                        farm.srv_state)
+    with jax.named_scope("power"):
+        if trace_sleep:
+            st_before = state.farm.srv_state
+        farm = server.refresh_idle_state(state.farm, cfg, state.t)
+        active = _active_jobs(state.jobs)
+        farm, sched = scheduler.provisioning_adjust(farm, cfg, state.sched,
+                                                    active)
+        farm = scheduler.wasp_adjust(farm, cfg, _pending_jobs(state.jobs),
+                                     state.t)
+        farm = scheduler.timer_transitions(farm, cfg, state.t)
+        state = replace(state, farm=farm, sched=sched)
+        if trace_sleep:
+            # awake -> sleep edges from the local power controllers
+            was_awake = (st_before == SrvState.ACTIVE) \
+                | (st_before == SrvState.IDLE)
+            asleep = (farm.srv_state == SrvState.PKG_C6) \
+                | (farm.srv_state == SrvState.S3) \
+                | (farm.srv_state == SrvState.OFF)
+            trace_mod.stage(recs, was_awake & asleep, TraceKind.SLEEP,
+                            jnp.arange(cfg.n_servers, dtype=jnp.int32), -1,
+                            farm.srv_state)
 
     if cfg.has_network:
-        if cheap:
-            # the flow set is unchanged (gating), so rates and link_flows
-            # stay valid — but ports/linecards still enter LPI on idle
-            # timeouts, which is a function of *time*, not of flow events
-            net = net_mod.update_switch_states(
-                state.net, state.net.link_flows, tc, cfg, state.t)
-            state = replace(state, net=net)
-        else:
-            # rate recomputation is only needed while flows are in flight —
-            # gate the (F, H) pass.  The no-flow branch must still ZERO
-            # link_flows (recompute_rates would): reusing last step's
-            # counts would pin ports ACTIVE forever after the final flow
-            # completes.
-            flows, link_flows = jax.lax.cond(
-                state.flows.active.any(),
-                lambda args: net_mod.recompute_rates(args[0], tc, state.t),
-                lambda args: (args[0], jnp.zeros_like(args[1])),
-                (state.flows, state.net.link_flows))
-            net = net_mod.update_switch_states(state.net, link_flows, tc,
-                                               cfg, state.t)
-            state = replace(state, flows=flows, net=net)
+        with jax.named_scope("flows"):
+            state = _update_flows(state, cfg, tc, cheap)
     return state
+
+
+def _update_flows(state: SimState, cfg: SimConfig, tc, cheap: bool):
+    """Flow rates and switch power states after the step's events."""
+    if cheap:
+        # the flow set is unchanged (gating), so rates and link_flows
+        # stay valid — but ports/linecards still enter LPI on idle
+        # timeouts, which is a function of *time*, not of flow events
+        net = net_mod.update_switch_states(
+            state.net, state.net.link_flows, tc, cfg, state.t)
+        return replace(state, net=net)
+    # rate recomputation is only needed while flows are in flight — gate
+    # the (F, H) pass.  The no-flow branch must still ZERO link_flows
+    # (recompute_rates would): reusing last step's counts would pin ports
+    # ACTIVE forever after the final flow completes.
+    flows, link_flows = jax.lax.cond(
+        state.flows.active.any(),
+        lambda args: net_mod.recompute_rates(args[0], tc, state.t),
+        lambda args: (args[0], jnp.zeros_like(args[1])),
+        (state.flows, state.net.link_flows))
+    net = net_mod.update_switch_states(state.net, link_flows, tc, cfg,
+                                       state.t)
+    return replace(state, flows=flows, net=net)
 
 
 # ==========================================================================
@@ -1046,13 +1077,16 @@ def _consume_cheap(state: SimState, cfg: SimConfig, tc, t_next):
     # "cheap_core" so the static auditor (analysis/) can budget it
     # separately from the full step
     with jax.named_scope("cheap_core"):
-        state = _advance_interval(state, cfg, tc, t_next)
+        with jax.named_scope("advance"):
+            state = _advance_interval(state, cfg, tc, t_next)
         recs = [] if cfg.trace.enabled else None
-        state = _apply_thermal_events(state, cfg, recs)
+        with jax.named_scope("thermal"):
+            state = _apply_thermal_events(state, cfg, recs)
         state = _apply_events(state, cfg, tc, cheap=True, recs=recs)
         if cfg.trace.enabled:
-            state = replace(state, trace=trace_mod.flush(
-                state.trace, cfg, state.t, recs))
+            with jax.named_scope("trace_flush"):
+                state = replace(state, trace=trace_mod.flush(
+                    state.trace, cfg, state.t, recs))
         return replace(state, events=state.events + 1)
 
 
@@ -1067,7 +1101,8 @@ def _macro_chew(state: SimState, cfg: SimConfig, tc):
 
     def body(carry):
         state, k, _ = carry
-        ok, t_next = _cheap_gate(state, cfg)
+        with jax.named_scope("next_event"):
+            ok, t_next = _cheap_gate(state, cfg)
         state = jax.lax.cond(
             ok, lambda s: _consume_cheap(s, cfg, tc, t_next),
             lambda s: s, state)
@@ -1080,24 +1115,29 @@ def _macro_chew(state: SimState, cfg: SimConfig, tc):
 
 def _full_step(state: SimState, cfg: SimConfig, tc=None) -> SimState:
     with jax.named_scope("full_step"):
-        t_next = next_event_time(state, cfg)
-        # a t_next at the INF sentinel means "no pending events": freeze
-        # time (the done check below will terminate the loop) instead of
-        # integrating energy over an unbounded interval
-        t_next = jnp.where(t_next >= INF / 2, state.t, t_next)
-        state = _advance_interval(state, cfg, tc, t_next)
+        with jax.named_scope("next_event"):
+            t_next = next_event_time(state, cfg)
+            # a t_next at the INF sentinel means "no pending events":
+            # freeze time (the done check below will terminate the loop)
+            # instead of integrating energy over an unbounded interval
+            t_next = jnp.where(t_next >= INF / 2, state.t, t_next)
+        with jax.named_scope("advance"):
+            state = _advance_interval(state, cfg, tc, t_next)
         recs = [] if cfg.trace.enabled else None
-        state = _apply_thermal_events(state, cfg, recs)
+        with jax.named_scope("thermal"):
+            state = _apply_thermal_events(state, cfg, recs)
         state = _apply_events(state, cfg, tc, cheap=False, recs=recs)
         if cfg.trace.enabled:
-            state = replace(state, trace=trace_mod.flush(
-                state.trace, cfg, state.t, recs))
+            with jax.named_scope("trace_flush"):
+                state = replace(state, trace=trace_mod.flush(
+                    state.trace, cfg, state.t, recs))
 
-        all_done = (~state.jobs.valid
-                    | (state.jobs.status == TaskStatus.DONE)).all() \
-            & (_next_arrival(state.jobs) >= INF)
-        if cfg.has_network:
-            all_done = all_done & ~state.flows.active.any()
+        with jax.named_scope("next_event"):
+            all_done = (~state.jobs.valid
+                        | (state.jobs.status == TaskStatus.DONE)).all() \
+                & (_next_arrival(state.jobs) >= INF)
+            if cfg.has_network:
+                all_done = all_done & ~state.flows.active.any()
         return replace(state, events=state.events + 1, done=all_done)
 
 
@@ -1117,8 +1157,10 @@ def sim_step(state: SimState, cfg: SimConfig, tc=None) -> SimState:
     state = replace(state, steps=state.steps + 1)
 
     if telemetry_on:
-        state = replace(state, telem=telemetry.accumulate_finishes(
-            state.telem, cfg, state.jobs, old_job_finish, old_task_finish))
+        with jax.named_scope("telemetry"):
+            state = replace(state, telem=telemetry.accumulate_finishes(
+                state.telem, cfg, state.jobs, old_job_finish,
+                old_task_finish))
     return state
 
 

@@ -20,7 +20,8 @@ from .types import INF, SimConfig, SimState
 class RunInfo:
     """Run provenance: host wall clock + the exact config that produced
     the result, for BENCH/CI artifacts and trace headers."""
-    wall_s: float                   # wall time of the timed engine run
+    wall_s: float                   # wall time of the engine run, less
+                                    # its jit work with profile=True
     steps: int                      # while-loop iterations (macro-steps)
     events: int                     # events retired
     events_per_s: float             # events / wall_s
@@ -211,8 +212,9 @@ def simulate(cfg: SimConfig, arrivals, specs, topo=None, tau=None,
     pools — (N,) 0/1 pool assignment (dual-timer low/high, WASP active/sleep)
     racks — (N,) rack ids for the thermal recirculation grouping (defaults
             to the topology's top-of-rack grouping, else i // rack_size)
-    profile — rerun the (now warm) engine once more to split JIT compile
-            time out of the wall clock (result.run_info.jit_compile_s)
+    profile — split the run's jit work (trace, lowering, compile) out of
+            its wall clock into result.run_info.jit_compile_s, from the
+            counters of analysis.retrace.watch()
     mesh  — run rack-sharded on this device mesh (core/shard_sim.py);
             ``cfg.partition.n_shards > 1`` with mesh=None builds one from
             the visible devices.  Results are bit-identical either way.
@@ -242,16 +244,19 @@ def simulate(cfg: SimConfig, arrivals, specs, topo=None, tau=None,
     else:
         def runner():
             return engine.run(state, cfg, tc)
+    if profile:
+        from ..analysis import retrace
+        retrace.watch()
+        before = retrace.jit_counts()
     t0 = time.perf_counter()
     final = jax.block_until_ready(runner())
     wall = time.perf_counter() - t0
     compile_s = float("nan")
     if profile:
-        t1 = time.perf_counter()
-        final = jax.block_until_ready(runner())
-        warm = time.perf_counter() - t1
-        compile_s = max(wall - warm, 0.0)
-        wall = warm
+        jit = retrace.jit_delta(before, retrace.jit_counts())
+        compile_s = sum(jit[k]["seconds"] for k in ("trace", "lower",
+                                                    "compile"))
+        wall -= compile_s
     res = summarize(final, cfg)
     n_ev = int(final.events)
     if sharded:

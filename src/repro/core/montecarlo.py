@@ -41,9 +41,10 @@ def batched_state(cfg: SimConfig, arrivals_b, specs, taus=None, topo=None):
     return state_b, tc
 
 
-def run_replicas(cfg: SimConfig, state_b, tc=None, mesh=None):
-    """vmap the engine over the replica axis; optionally shard_map the
-    replica batch over the mesh.
+def replica_jit(cfg: SimConfig, tc=None, mesh=None):
+    """The jitted replica program that :func:`run_replicas` runs: the
+    engine vmapped over the replica axis, and shard_mapped over ``mesh``
+    when one is given.  A new jit each time it is called.
 
     The replica batch maps onto every mesh axis EXCEPT the rack-sharding
     axis (``cfg.partition.axis``, normally "racks"): on a 2-D
@@ -54,15 +55,34 @@ def run_replicas(cfg: SimConfig, state_b, tc=None, mesh=None):
     runner = jax.vmap(functools.partial(engine.run.__wrapped__, cfg=cfg,
                                         tc=tc))
     if mesh is None:
-        return jax.jit(runner)(state_b)
+        return jax.jit(runner)
     from jax.sharding import PartitionSpec as P
 
     bax = tuple(a for a in mesh.axis_names if a != cfg.partition.axis)
     # prefix spec: replica dim 0 over the non-rack axes
     spec = P(bax) if bax else P()
     fn = jax.shard_map(runner, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                   check_vma=False)
-    return jax.jit(fn)(state_b)
+                       check_vma=False)
+    return jax.jit(fn)
+
+
+def run_replicas(cfg: SimConfig, state_b, tc=None, mesh=None):
+    """Run every replica of the batch to its end (:func:`replica_jit`).
+
+    Each call builds its jit anew, so each call traces, lowers and
+    compiles (or reads the compile cache) before it runs.  The four
+    stages run under the profiler spans ``run_replicas.trace``,
+    ``run_replicas.lower``, ``run_replicas.compile`` and
+    ``run_replicas.execute``, so a profile splits a call's host time."""
+    fn = replica_jit(cfg, tc, mesh)
+    with jax.profiler.TraceAnnotation("run_replicas.trace"):
+        traced = fn.trace(state_b)
+    with jax.profiler.TraceAnnotation("run_replicas.lower"):
+        lowered = traced.lower()
+    with jax.profiler.TraceAnnotation("run_replicas.compile"):
+        compiled = lowered.compile()
+    with jax.profiler.TraceAnnotation("run_replicas.execute"):
+        return compiled(state_b)
 
 
 def replica_stats(state_b, cfg: SimConfig):

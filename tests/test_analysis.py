@@ -224,6 +224,35 @@ def test_retrace_guard_counts_only_inside_guard():
         assert retraced() == []
 
 
+def test_jit_counts_see_each_replica_retrace_and_no_cached_run():
+    """watch() counts top-level traces: every run_replicas call builds a
+    new jit and traces it; a repeated engine.run hits the jit cache."""
+    from repro.core import engine, montecarlo
+    from repro.core.jobs import build_jobs, dag_single
+    from repro.core.types import SimConfig, SleepPolicy
+
+    cfg = SimConfig(n_servers=2, n_cores=1, max_jobs=4, tasks_per_job=1,
+                    sleep_policy=SleepPolicy.ALWAYS_ON, max_events=200)
+    arrs = np.asarray([[0.0, 0.1, 0.2, 0.3], [0.05, 0.1, 0.4, 0.5]])
+    specs = [dag_single(0.05)] * 4
+    retrace.watch()
+    state_b, tc = montecarlo.batched_state(cfg, arrs, specs)
+    before = retrace.jit_counts()
+    for _ in range(2):
+        jax.block_until_ready(montecarlo.run_replicas(cfg, state_b, tc))
+    d = retrace.jit_delta(before, retrace.jit_counts())
+    assert d["trace"]["count"] >= 2 and d["trace"]["seconds"] > 0
+    assert d["trace"]["by_fun"].get("run", 0) >= 2
+    assert d["compile"]["count"] >= 2
+
+    state, tc = engine.init_state(cfg, build_jobs(cfg, arrs[0], specs))
+    jax.block_until_ready(engine.run(state, cfg, tc))
+    before = retrace.jit_counts()
+    jax.block_until_ready(engine.run(state, cfg, tc))
+    d = retrace.jit_delta(before, retrace.jit_counts())
+    assert d["trace"]["count"] == 0 and d["compile"]["count"] == 0
+
+
 # ==========================================================================
 # the real engine, green end to end
 # ==========================================================================
