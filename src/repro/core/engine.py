@@ -808,24 +808,19 @@ def _drain_ready(state: SimState, cfg: SimConfig, recs=None):
 
 
 def _drain_ready_batched(state: SimState, cfg: SimConfig, recs=None):
-    """One multi-push: the first K READY tasks become QUEUED with FIFO
-    stamps written elementwise into their own task rows (no ring-slot
-    scatter).  The whole pass is gated on "any READY task" so quiet steps
-    stay free."""
+    """One multi-push in batch space: the first K READY tasks, compacted
+    into K slots in ascending tid order (server.compact_mask, no index op
+    per job-table row), become QUEUED with FIFO stamps written at their
+    own rows by K-slot scatters.  The whole pass is gated on "any READY
+    task" so quiet steps stay free."""
     is_ready = state.jobs.status == TaskStatus.READY
     JT0 = state.jobs.status.shape[0]
 
     def drain(args):
         state, _ = args
         jobs, farm = state.jobs, state.farm
-        K = cfg.ready_per_step
         JT = jobs.status.shape[0]
-        r = jnp.cumsum(is_ready) - 1                # rank among READY
-        sel = is_ready & (r < K)
-        # gather selected tids into (K,) batch slots, ascending tid order
-        tids = jnp.full((K,), -1, jnp.int32).at[jnp.where(sel, r, K)].set(
-            jnp.arange(JT, dtype=jnp.int32), mode="drop")
-        valid = tids >= 0
+        tids, valid, _ = server.compact_mask(is_ready, cfg.ready_per_step)
         srv = jnp.where(valid, jobs.server[jnp.clip(tids, 0)], -1)
 
         farm, ok, seq = server.queue_push_many(farm, cfg, srv, tids, valid)

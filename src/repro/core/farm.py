@@ -108,6 +108,8 @@ class SimResult:
     busy_core_seconds: float
     utilization: float              # busy core-seconds / (N*C*T)
     dropped: int
+    rank_fallbacks: int             # start passes that ranked the whole
+                                    # job table (congested or <= 128 rows)
     latencies: np.ndarray           # (J,) finished-job latencies (sec)
     # device-side telemetry summary (None when cfg.telemetry.enabled=False)
     telemetry: Optional[telemetry_mod.TelemetrySummary] = None
@@ -194,6 +196,7 @@ def summarize(state: SimState, cfg: SimConfig) -> SimResult:
         utilization=float(np.asarray(state.farm.busy_core_seconds).sum()
                           / max(N * C * t, 1e-12)),
         dropped=int(state.farm.dropped),
+        rank_fallbacks=int(state.farm.rank_fallbacks),
         latencies=lat,
         telemetry=(telemetry_mod.summarize(state, cfg)
                    if cfg.telemetry.enabled else None),

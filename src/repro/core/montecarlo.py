@@ -132,6 +132,7 @@ def replica_stats(state_b, cfg: SimConfig):
         "events": np.asarray(state_b.events),
         "finished": finished,
         "flows_dropped": np.asarray(state_b.flows.flows_dropped),
+        "rank_fallbacks": np.asarray(state_b.farm.rank_fallbacks),
     }
     if cfg.trace.enabled:
         # per-replica flight-recorder health: records evicted by wrap

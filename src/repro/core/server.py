@@ -137,44 +137,156 @@ def queued_rank(jobs: JobTable, cfg: SimConfig, queued, q_seq):
     return jnp.zeros((JT,), jnp.int32).at[order].set(rank_o)
 
 
+# largest K * JT that compact_mask searches by a (K, JT) compare-and-count;
+# above it, a binary search while its K * log2(JT) probes are fewer than
+# JT, else one scatter of JT updates.  On a TPU v5e the compare costs about
+# 4 ps an element, a probe or an update about 6-9 ns an index, so the
+# compare wins at JT = 500 (under vmap too) and at JT = 100,000 with K = 8,
+# and the search at JT = 100,000 with K = 128
+_COMPARE_ALL_MAX = 1 << 21
+
+
 def compact_mask(mask, K: int):
-    """Gather the first K set task ids of ``mask`` (JT,) into a (K,)
-    batch in ascending-tid order: one cumsum + one K-slot scatter.
-    Returns (tids (K,), valid (K,), covered — True iff mask.sum() <= K,
-    i.e. the batch holds EVERY set task)."""
+    """Gather the first K set ids of ``mask`` (JT,) into a (K,) batch in
+    ascending order.  The k-th set id is where the running count
+    ``cumsum(mask)`` first reaches k + 1: found by a (K, JT)
+    compare-and-count on small tables and a binary search with K indices
+    per probe on large ones, so no index op runs per row.  Only a batch
+    nearly as wide as the table scatters the ids, one update per row.
+    Returns (tids (K,), -1 in unused slots; valid (K,); covered — True iff
+    mask.sum() <= K, i.e. the batch holds EVERY set id)."""
     JT = mask.shape[0]
-    r = jnp.cumsum(mask) - 1
-    sel = mask & (r < K)
-    tids = jnp.full((K,), -1, jnp.int32).at[jnp.where(sel, r, K)].set(
-        jnp.arange(JT, dtype=jnp.int32), mode="drop")
-    return tids, tids >= 0, r[-1] < K
+    count = jnp.cumsum(mask, dtype=jnp.int32)
+    covered = count[-1] <= K
+    if K * JT > _COMPARE_ALL_MAX and K * JT.bit_length() >= JT:
+        tids = jnp.full((K,), -1, jnp.int32).at[
+            jnp.where(mask, count - 1, K)].set(
+            jnp.arange(JT, dtype=jnp.int32), mode="drop")
+        return tids, tids >= 0, covered
+    k = jnp.arange(K, dtype=jnp.int32)
+    valid = k < count[-1]
+    method = "compare_all" if K * JT <= _COMPARE_ALL_MAX else "scan"
+    tids = jnp.searchsorted(count, k + 1, method=method).astype(jnp.int32)
+    return jnp.where(valid, tids, -1), valid, covered
 
 
 # compact-batch size for start resolution: when at most this many tasks
 # are QUEUED farm-wide (the overwhelmingly common case — drains are
 # bounded by ready_per_step and starts immediately consume what they
-# push), FIFO ranks come from a pairwise comparison inside a compacted
-# batch; only genuinely congested steps pay the full-JT argsort rank
+# push), the start pass runs over a compacted batch of the queued tasks;
+# only genuinely congested steps pay the job-table-wide argsort rank
 COMPACT_Q = 128
+
+
+def _start_dense(farm: ServerFarm, cfg: SimConfig, jobs: JobTable, now,
+                 freq, n_start, queued):
+    """Job-table-space start, for congested steps (more than COMPACT_Q
+    queued tasks) and tables no wider than COMPACT_Q: the argsort FIFO
+    rank over every row, elementwise task side, (N, C) core side."""
+    N, C = cfg.n_servers, cfg.n_cores
+    JT = jobs.status.shape[0]
+    rank = queued_rank(jobs, cfg, queued, farm.q_seq)
+    srv = jnp.clip(jobs.server, 0)
+    # task side: elementwise
+    start_t = queued & (rank < n_start[srv])                    # (JT,)
+    if freq is None:
+        svc = jobs.service / cfg.core_freq
+    else:
+        svc = jobs.service / freq[srv]
+    end_t = (now + svc).astype(jobs.task_end.dtype)
+    status = jnp.where(start_t, TaskStatus.RUNNING, jobs.status)
+    task_end = jnp.where(start_t, end_t, jobs.task_end)
+    start_at = jnp.where(
+        start_t, jnp.asarray(now, jobs.start_at.dtype), jobs.start_at)
+    jobs = replace(jobs, status=status, task_end=task_end,
+                   start_at=start_at)
+
+    # core side: the r-th starting task of server s takes the r-th free
+    # core; build the (s, r) -> task table with one scatter, then fill
+    # cores elementwise (the busy_until expression is the same float math
+    # as end_t, so task_end stays bit-equal)
+    free = farm.core_busy_until >= INF                          # (N, C)
+    row = jnp.where(start_t, srv, N)
+    col = jnp.clip(jnp.where(start_t, rank, 0), 0, C - 1)
+    tid_at = jnp.full((N, C), JT, jnp.int32).at[row, col].set(
+        jnp.arange(JT, dtype=jnp.int32), mode="drop")
+    fr = jnp.cumsum(free, axis=1) - 1                           # free rank
+    start_c = free & (fr < n_start[:, None])                    # (N, C)
+    tid_c = jnp.take_along_axis(tid_at, jnp.clip(fr, 0, C - 1), axis=1)
+    if freq is None:
+        svc_c = jobs.service[jnp.clip(tid_c, 0, JT - 1)] / cfg.core_freq
+    else:
+        svc_c = jobs.service[jnp.clip(tid_c, 0, JT - 1)] / freq[:, None]
+    busy_until = (now + svc_c).astype(farm.core_busy_until.dtype)
+    farm = replace(
+        farm,
+        core_busy_until=jnp.where(start_c, busy_until, farm.core_busy_until),
+        q_len=farm.q_len - n_start)
+    return farm, jobs
+
+
+def _start_batch(farm: ServerFarm, cfg: SimConfig, jobs: JobTable, now,
+                 freq, n_start, tids, valid):
+    """Batch-space start over ``tids`` (K,), the compacted queued tasks,
+    which must hold EVERY queued task: FIFO ranks from a pairwise count
+    inside the batch, then K-slot gathers and scatters for the task and
+    core sides — no index op per job-table row or per core.  Gives the
+    farm and jobs of _start_dense on the same state, bit for bit."""
+    N = cfg.n_servers
+    JT = jobs.status.shape[0]
+    tq = jnp.clip(tids, 0)
+    srv = jnp.clip(jobs.server[tq], 0)                          # (K,)
+    seq = jobs.enqueue_seq[tq]
+    # pairwise FIFO rank inside the batch — equal to the dense rank
+    # because the batch covers every queued task; the compare is the
+    # wrap-safe int32 diff (see queued_rank)
+    same = valid[None, :] & valid[:, None] & (srv[None, :] == srv[:, None])
+    rank = jnp.sum(same & ((seq[None, :] - seq[:, None]) < 0), axis=1,
+                   dtype=jnp.int32)
+    start = valid & (rank < n_start[srv])
+    # the float math of _start_dense, per starting task
+    if freq is None:
+        svc = jobs.service[tq] / cfg.core_freq
+    else:
+        svc = jobs.service[tq] / freq[srv]
+
+    # task side: a K-slot start mask for the elementwise status and
+    # start_at; the end stamps scattered at their rows
+    row = jnp.where(start, tids, JT)
+    start_t = jnp.zeros((JT,), bool).at[row].set(True, mode="drop")
+    jobs = replace(
+        jobs,
+        status=jnp.where(start_t, TaskStatus.RUNNING, jobs.status),
+        task_end=jobs.task_end.at[row].set(
+            (now + svc).astype(jobs.task_end.dtype), mode="drop"),
+        start_at=jnp.where(start_t, jnp.asarray(now, jobs.start_at.dtype),
+                           jobs.start_at))
+
+    # core side: the task of rank r takes its server's r-th free core
+    free = farm.core_busy_until[srv] >= INF                     # (K, C)
+    fr = jnp.cumsum(free, axis=1) - 1
+    core = jnp.argmax(free & (fr == rank[:, None]), axis=1)
+    busy_until = farm.core_busy_until.at[jnp.where(start, srv, N), core].set(
+        (now + svc).astype(farm.core_busy_until.dtype), mode="drop")
+    return replace(farm, core_busy_until=busy_until,
+                   q_len=farm.q_len - n_start), jobs
 
 
 def try_start(farm: ServerFarm, cfg: SimConfig, jobs: JobTable, now,
               freq=None):
     """Start as many queued tasks as there are free cores, FIFO per
-    server, in one task-space pass.
+    server: a queued task starts iff its per-server FIFO rank is below
+    its server's free-core count, and the r-th starting task of a server
+    takes its r-th free core.
 
-    Task-side bookkeeping (status -> RUNNING, task_end stamp) is fully
-    elementwise in task space: a queued task starts iff its per-server
-    FIFO rank is below its server's free-core count.  The core array is
-    rebuilt from a (server, rank) -> task table (one small scatter)
-    instead of the seed's (N, Q) ring gather + two (N·C)-row core->task
-    scatters, which serialized on XLA:CPU.
-
-    FIFO ranks normally come from a COMPACT_Q-wide gathered batch via a
-    pairwise count (queues are near-empty in steady state); steps with
-    more queued tasks than that fall back to the full argsort rank.
-    Both paths define the identical rank, so the runtime choice never
-    changes the dynamics.
+    When at most COMPACT_Q tasks are queued farm-wide (queues are
+    near-empty in steady state), the pass works in batch space over the
+    compacted queued tasks (_start_batch), so its cost follows the work
+    in flight, not the job table.  Congested steps with more queued tasks
+    than that, and tables no wider than COMPACT_Q, take the job-table-space
+    argsort rank (_start_dense) and count one ``farm.rank_fallbacks``.
+    Both paths define the identical rank and float math, so the runtime
+    choice never changes the dynamics.
 
     ``freq`` (N,) optionally overrides the scalar cfg.core_freq with a
     per-server effective frequency (thermal throttling); None keeps the
@@ -182,7 +294,6 @@ def try_start(farm: ServerFarm, cfg: SimConfig, jobs: JobTable, now,
 
     Returns (farm, jobs).
     """
-    N, C = cfg.n_servers, cfg.n_cores
     JT = jobs.status.shape[0]
     awake = (farm.srv_state == SrvState.ACTIVE) \
         | (farm.srv_state == SrvState.IDLE)
@@ -190,79 +301,28 @@ def try_start(farm: ServerFarm, cfg: SimConfig, jobs: JobTable, now,
     n_free = free.sum(axis=1, dtype=jnp.int32)
     n_start = jnp.where(awake, jnp.minimum(n_free, farm.q_len), 0)
 
-    def apply_start(farm, jobs, rank):
-        queued = jobs.status == TaskStatus.QUEUED
-        srv = jnp.clip(jobs.server, 0)
-        # task side: elementwise
-        start_t = queued & (rank < n_start[srv])                # (JT,)
-        if freq is None:
-            svc = jobs.service / cfg.core_freq
-        else:
-            svc = jobs.service / freq[srv]
-        end_t = (now + svc).astype(jobs.task_end.dtype)
-        status = jnp.where(start_t, TaskStatus.RUNNING, jobs.status)
-        task_end = jnp.where(start_t, end_t, jobs.task_end)
-        start_at = jnp.where(
-            start_t, jnp.asarray(now, jobs.start_at.dtype), jobs.start_at)
-        jobs = replace(jobs, status=status, task_end=task_end,
-                       start_at=start_at)
-
-        # core side: the r-th starting task of server s takes the r-th
-        # free core; build the (s, r) -> task table with one small
-        # scatter, then fill cores elementwise (the busy_until expression
-        # is the same float math as end_t, so task_end stays bit-equal)
-        row = jnp.where(start_t, srv, N)
-        col = jnp.clip(jnp.where(start_t, rank, 0), 0, C - 1)
-        tid_at = jnp.full((N, C), JT, jnp.int32).at[row, col].set(
-            jnp.arange(JT, dtype=jnp.int32), mode="drop")
-        fr = jnp.cumsum(free, axis=1) - 1                       # free rank
-        start_c = free & (fr < n_start[:, None])                # (N, C)
-        tid_c = jnp.take_along_axis(tid_at, jnp.clip(fr, 0, C - 1), axis=1)
-        if freq is None:
-            svc_c = jobs.service[jnp.clip(tid_c, 0, JT - 1)] / cfg.core_freq
-        else:
-            svc_c = jobs.service[jnp.clip(tid_c, 0, JT - 1)] / freq[:, None]
-        busy_until = (now + svc_c).astype(farm.core_busy_until.dtype)
-        farm = replace(
-            farm,
-            core_busy_until=jnp.where(start_c, busy_until,
-                                      farm.core_busy_until),
-            q_len=farm.q_len - n_start)
-        return farm, jobs
-
     def do(args):
         farm, jobs = args
         queued = jobs.status == TaskStatus.QUEUED
 
         def dense(args):
             farm, jobs = args
-            return apply_start(farm, jobs,
-                               queued_rank(jobs, cfg, queued, farm.q_seq))
+            farm, jobs = _start_dense(farm, cfg, jobs, now, freq, n_start,
+                                      queued)
+            return (replace(farm, rank_fallbacks=farm.rank_fallbacks + 1),
+                    jobs)
 
         if JT <= COMPACT_Q:
             return dense(args)
 
         tids, valid, covered = compact_mask(queued, COMPACT_Q)
 
-        def compact(args):
+        def batch(args):
             farm, jobs = args
-            srv = jnp.clip(jobs.server, 0)
-            tq = jnp.clip(tids, 0)
-            srv_b = jnp.where(valid, srv[tq], N)
-            seq_b = jobs.enqueue_seq[tq]
-            # pairwise FIFO rank inside the batch — equal to the dense
-            # rank because the batch covers every queued task; the
-            # compare is the wrap-safe int32 diff (see queued_rank)
-            same = valid[None, :] & valid[:, None] \
-                & (srv_b[None, :] == srv_b[:, None])
-            rank_b = jnp.sum(
-                same & ((seq_b[None, :] - seq_b[:, None]) < 0),
-                axis=1).astype(jnp.int32)
-            rank = jnp.zeros((JT,), jnp.int32).at[
-                jnp.where(valid, tids, JT)].set(rank_b, mode="drop")
-            return apply_start(farm, jobs, rank)
+            return _start_batch(farm, cfg, jobs, now, freq, n_start, tids,
+                                valid)
 
-        return jax.lax.cond(covered, compact, dense, args)
+        return jax.lax.cond(covered, batch, dense, args)
 
     return jax.lax.cond((n_start > 0).any(), do, lambda a: a, (farm, jobs))
 

@@ -511,6 +511,8 @@ class ServerFarm:
     busy_core_seconds: jnp.ndarray  # (N,)
     wake_count: jnp.ndarray         # (N,) number of sleep->active transitions
     dropped: jnp.ndarray            # () tasks dropped on full queues
+    rank_fallbacks: jnp.ndarray     # () start passes that took the dense
+                                    # job-table-wide argsort rank
 
 
 @pytree_dataclass
@@ -697,6 +699,7 @@ def init_farm(cfg: SimConfig) -> ServerFarm:
         busy_core_seconds=jnp.zeros((N,), jnp.float32),
         wake_count=jnp.zeros((N,), jnp.int32),
         dropped=jnp.zeros((), jnp.int32),
+        rank_fallbacks=jnp.zeros((), jnp.int32),
     )
 
 

@@ -169,6 +169,7 @@ def test_macro_step_congested_queue_argsort_fallback():
     arr = 0.001 * np.arange(n_jobs)
     specs = [dag_single(1.0) for _ in range(n_jobs)]
     final = _sweep_ks(cfg, arr, specs)
+    assert int(final.farm.rank_fallbacks) > 0       # the dense rank ran
     orc = OracleSim(cfg, arr, specs).run()
     fin = np.asarray(final.jobs.job_finish)
     ok = fin < INF / 2
