@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``: the program's state after the
 timed calls against the plain reference's, number by number, each against
-its own limit (``bench/limits/<cell>.json``).
+its own limit (``bench/limits/<cell>.json``, whose names are the numbers
+judged).
 
-Every number is a gap that reads 0 where the two agree exactly:
+Every number a farm's check computes (``gaps``, ``NUMBERS``) is a gap
+that reads 0 where the two agree exactly:
 
 - ``sim_time_gap_s``: simulated time reached, seconds;
 - ``placement_mismatch``: jobs placed on another server;
@@ -72,11 +74,20 @@ def bin_moves(a, b) -> float:
 def worst(readings: list) -> dict:
     """Each number's largest reading over several farms (a sweep's
     checked replicas)."""
-    return {k: max(r[k] for r in readings) for k in NUMBERS}
+    return {k: max(r[k] for r in readings) for k in readings[0]}
 
 
 def judge(numbers: dict, limits: dict) -> tuple:
-    """(all within limits, {name: {"value", "limit"}}) in NUMBERS order."""
-    checks = {k: {"value": numbers[k], "limit": limits[k]} for k in NUMBERS}
-    ok = all(numbers[k] <= limits[k] for k in NUMBERS)
+    """(all within limits, {name: {"value", "limit"}}) for every name of
+    ``limits`` (the cell's ``bench/limits/<cell>.json``), in its order.
+    A number with no limit, or a limit with no number, is an error: what
+    the check computes is all judged, and all that is judged is
+    computed."""
+    unjudged = [k for k in numbers if k not in limits]
+    missing = [k for k in limits if k not in numbers]
+    if unjudged or missing:
+        raise KeyError(f"compared numbers without a limit: {unjudged}; "
+                       f"limits without a number: {missing}")
+    checks = {k: {"value": numbers[k], "limit": limits[k]} for k in limits}
+    ok = all(numbers[k] <= limits[k] for k in limits)
     return ok, checks

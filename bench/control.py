@@ -48,7 +48,7 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     from bench import loader
     cell = loader.cell(args.workload)
-    entry = loader.module("entries", cell.traffic["entry"])
+    entry = loader.entry(cell)
     for seed in args.seeds:
         print(json.dumps({
             "workload": cell.name, "seed": seed,

@@ -49,8 +49,8 @@ class Runner:
         cfg_w = dataclasses.replace(cfg, max_events=self.W)
         self.cfg = dataclasses.replace(cfg, max_events=self.W + self.E)
         t0 = time.perf_counter()
-        for c in (cfg_w, self.cfg):
-            engine.run.lower(state, c, tc).compile()
+        engine.run.lower(state, cfg_w, tc).compile()
+        self.compiled = engine.run.lower(state, self.cfg, tc).compile()
         self.compile_s = time.perf_counter() - t0
 
         s_w = jax.block_until_ready(engine.run(state, cfg_w, tc))
@@ -77,6 +77,11 @@ class Runner:
 
     def outputs(self, out):
         return program.outputs(out, self.J)
+
+    def program_text(self) -> str:
+        """The compiled HLO text of the timed calls' program, from
+        set-up's compile."""
+        return self.compiled.as_text()
 
     def check(self, outputs) -> dict:
         return compare.gaps(outputs, _reference(self.cell, self.inputs))

@@ -51,6 +51,12 @@ class Runner:
         return (not counts["done"]
                 or counts["replica_events"] != self.expected)
 
+    def program_text(self) -> str:
+        """The compiled HLO text of the program each call builds (read
+        back from the compile cache)."""
+        return montecarlo.replica_jit(self.cfg, self.tc).lower(
+            self.state_b).compile().as_text()
+
     def outputs(self, out):
         out = jax.device_get(out)
         return [program.outputs(out, self.J, replica=r)

@@ -1,0 +1,15 @@
+"""Device milliseconds per macro-step of a traced farm call in no engine
+region (``other``): loop control, the loop's carries and what XLA fused
+across regions' edges; the leaf time of its device ops, mapped to
+regions through the compiled program's HLO text
+(``bench/regions.py``)."""
+LAYER = "engine regions"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "events_per_s"
+
+
+def read(run):
+    regions = run["regions"]
+    return regions["other"] if regions else None

@@ -12,45 +12,45 @@ fused instructions span more than one region is reported beside, so that
 readers know how blurred the attribution is.  ``run_replicas`` writes
 the host spans of its stages (``run_replicas.trace``, ``.lower``,
 ``.compile``, ``.execute``) into the same trace, on the profiler's
-clock.
+clock.  The compiled text comes from the entry's runner
+(``program_text``); :func:`per_call` is the one reduction of a traced
+call, for ``bench/run.py --trace 1`` and so for this script.  It refuses
+a call that ran another module than the text's, or device ops that the
+text does not hold: such time would land in no region, or in the wrong
+one, without a sign.
 
-Run as a script on a TPU, it measures one cell region by region: set-up
-as the harness does it, with the program's jit counters on
-(``analysis.retrace.watch``), a window of calls, one profiled call, and
-the program's HLO text; one JSON line on stdout, and with ``--out`` the
-same line, the trace and the HLO text in that directory:
+Run as a script on a TPU, it runs one cell as ``bench/run.py --trace 1``
+does and reports the traced call region by region, with the regions
+that have no metric, the busy, leaf and mixed time, the top ops, the
+program spans against the dispatch gap and the window's jit counts; one
+JSON line on stdout, and with ``--out`` the same line, the trace and the
+HLO text in that directory:
 
     python bench/regions.py --workload <cell> --seed <n> --seconds <s>
 """
 from __future__ import annotations
 
-import time
-
-T0 = time.perf_counter()
-
-import collections  # noqa: E402
-import json  # noqa: E402
-import os  # noqa: E402
-import pathlib  # noqa: E402
-import re  # noqa: E402
-import shutil  # noqa: E402
-import statistics  # noqa: E402
-import sys  # noqa: E402
+import collections
+import json
+import os
+import pathlib
+import re
+import shutil
+import statistics
+import sys
 
 if __package__ in (None, ""):
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
-from bench import trace_reduce  # noqa: E402
+from bench import trace_reduce, tracing  # noqa: E402
 
 # the engine's regions, in step order (core/engine.py)
 REGIONS = ("next_event", "advance", "completions", "admission",
            "drain_start", "power", "flows", "thermal", "trace_flush",
            "telemetry")
 OTHER = "other"
-# the host spans run_replicas writes around its stages
-PROGRAM_SPANS = ("run_replicas.trace", "run_replicas.lower",
-                 "run_replicas.compile", "run_replicas.execute")
-RETRACE_SPANS = PROGRAM_SPANS[:3]
+# the program spans of a replica sweep's per-call re-trace
+RETRACE_SPANS = tracing.PROGRAM_SPANS[:3]
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _FUSION_CALLS = re.compile(r"\bfusion\(.*?\bcalls=%?([\w.\-]+)")
@@ -118,9 +118,11 @@ def reduce(ops: dict, table: dict, window: tuple, top: int = 10) -> dict:
     """Leaf time of each device's ops inside ``window`` (start, end ns),
     by region, averaged over devices (seconds): {"seconds": {region: s},
     "busy_s" (union of the leaves), "leaf_s" (their sum), "mixed_s",
-    "ops": [[op, s, region, mixed]] (device 0's top ops)}."""
+    "ops": [[op, s, region, mixed]] (device 0's top ops), "unmapped":
+    {op: s} (ops that ``table`` does not hold, in no region)}."""
     lo, hi = window
     per, mixed, busy, top_ops = collections.Counter(), 0, 0, []
+    unmapped = collections.Counter()
     for i, events in enumerate(ops.values()):
         leaf = [ev for ev in trace_reduce.leaves(events)
                 if ev[1] < hi and ev[1] + ev[2] > lo]
@@ -128,48 +130,24 @@ def reduce(ops: dict, table: dict, window: tuple, top: int = 10) -> dict:
         for name, s, d in leaf:
             t = min(s + d, hi) - max(s, lo)
             op = trace_reduce.op_name(name)
-            region, mix = table.get(op, (OTHER, False))
+            if op not in table:
+                unmapped[op] += t
+                continue
+            region, mix = table[op]
             per[region] += t
             mixed += t if mix else 0
             by_op[op] += t
         busy += sum(e - s for s, e in trace_reduce.union(
             [(max(s, lo), min(s + d, hi)) for _, s, d in leaf]))
         if i == 0:
-            top_ops = [[op, t * 1e-9, *table.get(op, (OTHER, False))]
+            top_ops = [[op, t * 1e-9, *table[op]]
                        for op, t in by_op.most_common(top)]
     n = max(len(ops), 1)
     return {"seconds": {r: per[r] / n * 1e-9 for r in REGIONS + (OTHER,)},
             "busy_s": busy / n * 1e-9,
             "leaf_s": sum(per.values()) / n * 1e-9,
-            "mixed_s": mixed / n * 1e-9, "ops": top_ops}
-
-
-def extract(path) -> dict:
-    """From a profiler trace: {"ops": {plane: [(op, start_ns, dur_ns)]}
-    (each device's "XLA Ops" line), "modules": [(module, start_ns,
-    dur_ns)] (each device's "XLA Modules" line: ``jit_run(<id>)``) and
-    "program": [(span, start_ns, dur_ns)] (``PROGRAM_SPANS``)}.  TPU op
-    events carry no ``op_name``, only their times, so the region of an
-    op comes from the program's HLO text (:func:`hlo_regions`)."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(str(path))
-    out = {"ops": {}, "modules": [], "program": []}
-    for plane in pd.planes:
-        if plane.name.startswith("/device:"):
-            for line in plane.lines:
-                evs = [(ev.name, ev.start_ns, ev.duration_ns)
-                       for ev in line.events]
-                if line.name == "XLA Ops":
-                    out["ops"][plane.name] = evs
-                elif line.name == "XLA Modules":
-                    out["modules"] += evs
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                out["program"] += [(ev.name, ev.start_ns, ev.duration_ns)
-                                   for ev in line.events
-                                   if ev.name in PROGRAM_SPANS]
-    out["program"].sort(key=lambda p: p[1])
-    return out
+            "mixed_s": mixed / n * 1e-9, "ops": top_ops,
+            "unmapped": {op: t / n * 1e-9 for op, t in unmapped.items()}}
 
 
 def modules_in(modules, window: tuple) -> dict:
@@ -190,79 +168,64 @@ def span_seconds(spans, window: tuple) -> dict:
     for name, s, d in spans:
         if s >= lo and s + d <= hi:
             out[name] += d * 1e-9
-    return {name: out[name] for name in PROGRAM_SPANS if name in out}
+    return {name: out[name] for name in tracing.PROGRAM_SPANS
+            if name in out}
 
 
-def _program_text(entry: str, runner) -> str:
-    """The compiled HLO text of the program the entry's calls run."""
-    if entry == "farm":
-        from repro.core import engine
-        compiled = engine.run.lower(runner.s_w, runner.cfg,
-                                    runner.tc).compile()
-    else:
-        from repro.core import montecarlo
-        compiled = montecarlo.replica_jit(runner.cfg, runner.tc).lower(
-            runner.state_b).compile()
-    return compiled.as_text()
+def per_call(runner, trace: dict, counts: dict) -> dict:
+    """The traced call region by region: the device ops of ``trace``
+    (``tracing.extract``) inside the harness's ``call`` span, mapped to
+    regions through ``runner.program_text()``, and the program spans
+    inside that span.  {"module", "region_ms": {region: ms per loop
+    iteration}, ``other`` included, "busy_ms", "leaf_ms", "mixed_ms" (per
+    iteration, ``counts["steps"]`` of the traced call), "spans": {span:
+    s}, "ops": device 0's top ops [[op, s, region, mixed]], "call":
+    (start, end ns)}.  Raises ValueError where the call ran a module
+    other than the text's, or none, or an op the text does not hold."""
+    module, table = hlo_regions(runner.program_text())
+    (lo, hi), = [(s, s + d) for n, s, d in trace["host"] if n == "call"]
+    ran = modules_in(trace["modules"], (lo, hi))
+    if set(ran) != {module}:
+        raise ValueError(f"the traced call ran the modules {ran}, not "
+                         f"{module!r} alone, whose HLO text maps its ops")
+    r = reduce(trace["device"], table, (lo, hi))
+    if r["unmapped"]:
+        worst = sorted(r["unmapped"].items(), key=lambda o: -o[1])[:5]
+        raise ValueError(f"{sum(r['unmapped'].values())!r} s of device "
+                         f"ops missing from the HLO text of {module!r}, "
+                         f"e.g. {worst}")
+    per_ms = 1e3 / counts["steps"]
+    return {"module": module,
+            "region_ms": {k: s * per_ms for k, s in r["seconds"].items()},
+            "busy_ms": r["busy_s"] * per_ms, "leaf_ms": r["leaf_s"] * per_ms,
+            "mixed_ms": r["mixed_s"] * per_ms,
+            "spans": span_seconds(trace["program"], (lo, hi)),
+            "ops": r["ops"], "call": (lo, hi)}
 
 
-def measure(cell, seed: int, seconds: float, trace_dir,
-            save_dir=None) -> dict:
-    """Set-up with the jit counters on, a window, one profiled call and
-    its reduction by region and by host span; with ``save_dir``, the
-    trace and the HLO text are copied there."""
-    from bench import loader, program, tracing, window  # noqa: F401
-    # (program puts the repo's src/ on the path)
-    from repro.analysis import retrace
-    retrace.watch()
-    entry = cell.traffic["entry"]
-    runner = loader.module("entries", entry).setup(cell, seed)
-    setup_s = time.perf_counter() - T0
-    j0 = retrace.jit_counts()
-    calls, last = window.measure(runner.call, runner.counts, seconds)
-    j1 = retrace.jit_counts()
-    del last
-    out, path = tracing.capture(runner.call, trace_dir)
-    j2 = retrace.jit_counts()
-    counts = runner.counts(out)
-    del out
-    text = _program_text(entry, runner)
-    module, table = hlo_regions(text)
-    if save_dir is not None:
-        shutil.copy(path, save_dir / f"{cell.name}.{seed}.xplane.pb")
-        (save_dir / f"{cell.name}.{seed}.hlo.txt").write_text(text)
-
-    harness = tracing.extract(path)
-    (lo, hi), = [(s, s + d) for n, s, d in harness["host"] if n == "call"]
-    tr = extract(path)
-    by_region = reduce(tr["ops"], table, (lo, hi))
-    idle = trace_reduce.reduce(harness)
-    spans = span_seconds(tr["program"], (lo, hi))
-    steps = counts["steps"]
-    ms = {r: s * 1e3 / steps for r, s in by_region["seconds"].items()}
-    dispatch = sum(s for n, s in idle["gaps"] if n == "dispatch")
-    retrace_s = sum(spans.get(n, 0.0) for n in RETRACE_SPANS)
-    window_jit = retrace.jit_delta(j0, j1)
+def report(result: dict, kept: dict) -> dict:
+    """What the script adds to a ``bench/run.py --trace 1`` result
+    (``result``; ``kept`` from ``run.run``): every region, the busy,
+    leaf and mixed time, the program spans against the dispatch gap."""
+    by, reduced, calls = kept["regions"], kept["trace"], kept["calls"]
+    jit = kept["jit_window"]
     return {
-        "cell": cell.name, "seed": seed, "setup_s": setup_s,
-        "steps": steps, "module": module,
-        "region_ms": ms,
-        "busy_ms": by_region["busy_s"] * 1e3 / steps,
-        "leaf_ms": by_region["leaf_s"] * 1e3 / steps,
-        "mixed_share": by_region["mixed_s"] / max(by_region["leaf_s"],
-                                                  1e-30),
-        "ops": by_region["ops"],
-        "program_spans": spans, "retrace_s": retrace_s,
-        "dispatch_gap_s": dispatch,
-        "window_s": idle["window_s"], "busy_s": idle["busy_s"],
-        "gaps": idle["gaps"][:3],
-        "window_calls": len(calls),
+        "cell": kept["cell"], "seed": kept["seed"],
+        "correct": result["correct"], "setup_s": kept["setup_s"],
+        "steps": kept["traced"]["steps"], "module": by["module"],
+        "region_ms": by["region_ms"], "busy_ms": by["busy_ms"],
+        "leaf_ms": by["leaf_ms"],
+        "mixed_share": by["mixed_ms"] / max(by["leaf_ms"], 1e-30),
+        "ops": by["ops"], "program_spans": by["spans"],
+        "retrace_s": sum(by["spans"].get(n, 0.0) for n in RETRACE_SPANS),
+        "dispatch_gap_s": sum(s for n, s in reduced["gaps"]
+                              if n == "dispatch"),
+        "window_s": reduced["window_s"], "busy_s": reduced["busy_s"],
+        "gaps": reduced["gaps"][:3], "window_calls": len(calls),
         "median_call_s": statistics.median(c.end - c.start for c in calls),
-        "traces_per_call": window_jit["trace"]["count"] / len(calls),
-        "jit_window": window_jit, "jit_traced": retrace.jit_delta(j1, j2),
-        # the modules that ran in the call: only the one whose HLO text
-        # mapped the ops
-        "modules": modules_in(tr["modules"], (lo, hi)),
+        "traces_per_call": jit["trace"]["count"] / len(calls),
+        "jit_window": jit, "metrics": result["metrics"],
+        "checks": result["checks"],
     }
 
 
@@ -278,17 +241,22 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from bench import loader, run
     cell = loader.cell(args.workload)
-    if run.device_check(cell.chips) is None:
+    devices = run.device_check(cell.chips)
+    if devices is None:
         return 3
     run.enable_compile_cache()
+    kept = {}
+    result = run.run(argparse.Namespace(
+        workload=args.workload, seed=args.seed, seconds=args.seconds,
+        trace=1), cell, devices, kept=kept)
+    line = json.dumps(report(result, kept))
     if args.out is not None:
         args.out.mkdir(parents=True, exist_ok=True)
-    result = measure(cell, args.seed, args.seconds, run.TRACE_DIR,
-                     args.out)
-    line = json.dumps(result)
-    if args.out is not None:
-        (args.out / f"{args.workload}.{args.seed}.json").write_text(
-            line + "\n")
+        stem = args.out / f"{args.workload}.{args.seed}"
+        shutil.copy(kept["trace_path"], f"{stem}.xplane.pb")
+        pathlib.Path(f"{stem}.hlo.txt").write_text(
+            kept["runner"].program_text())
+        pathlib.Path(f"{stem}.json").write_text(line + "\n")
     print(line, flush=True)
     return 0
 

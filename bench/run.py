@@ -6,12 +6,19 @@ The cell (``<config>.<traffic>`` in ``BENCHMARK.json``) names its
 configuration and traffic mix; its traffic names the entry of the program
 that the window drives (``bench/entries/<entry>.py``).  Set-up builds the
 inputs from ``--seed``, compiles and warms up; the window then calls the
-entry back to back for ``--seconds`` (``bench/window.py``).  With
-``--trace 1`` one more call runs under the profiler and the cell's
-per-layer metrics are reported instead of its end-to-end ones.  Once the
-window has closed, the program's outputs are compared with the plain
-reference (``bench/compare.py``); each number compared is printed beside
-its limit, last on standard error and last in the result line.
+entry back to back for ``--seconds`` (``bench/window.py``).  Each
+end-to-end metric but ``setup_s`` is the cell's rate: the events of every
+call of the window per second of it.  With ``--trace 1`` the program's
+jit counters are on (``analysis.retrace.watch``), one more call runs
+under the profiler, and the cell's per-layer metrics are reported
+instead of its end-to-end ones: each reader (``bench/metrics/<name>.py``)
+reads the set-up's times, the window's calls and jit counts
+(``jit_window``), and the traced call's counts (``traced``), trace
+reduction (``trace``), device ms per engine region and loop iteration
+(``regions``) and program spans (``spans``).  Once the window has
+closed, the program's outputs are compared with the plain reference
+(``bench/compare.py``); each number compared is printed beside its
+limit, last on standard error and last in the result line.
 
 Needs a TPU with as many chips as the cell asks for: anywhere else it
 exits with code 3 and prints no result.
@@ -67,15 +74,26 @@ def memory_peak(devices) -> int:
     return max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
 
 
-def run(args, cell, devices, entry_setup=None) -> dict:
+def run(args, cell, devices, entry_setup=None, kept=None) -> dict:
     """Set-up, window, optional traced call and the check; the result
-    object.  ``entry_setup`` replaces the entry's set-up (tests)."""
-    from bench import compare, loader, tracing, trace_reduce, window
-    entry = loader.module("entries", cell.traffic["entry"])
+    object.  ``entry_setup`` replaces the entry's set-up (tests); a
+    traced run fills ``kept``, where given, with what its readers read,
+    the runner and the trace's path (``bench/regions.py``)."""
+    from bench import compare, loader, regions, tracing, trace_reduce, \
+        window
+    if args.trace:
+        from bench import program  # noqa: F401  (the program's src/)
+        from repro.analysis import retrace
+        retrace.watch()
+    entry = loader.entry(cell)
     runner = (entry_setup or entry.setup)(cell, args.seed)
     setup_s = time.perf_counter() - T0
 
+    if args.trace:
+        j0 = retrace.jit_counts()
     calls, last = window.measure(runner.call, runner.counts, args.seconds)
+    if args.trace:
+        jit_window = retrace.jit_delta(j0, retrace.jit_counts())
     failed = sum(runner.failed(c.counts) for c in calls)
     if runner.compile_s is None:         # first call compiled, in set-up
         runner.compile_s = runner.first_call_s - (calls[0].end
@@ -86,13 +104,15 @@ def run(args, cell, devices, entry_setup=None) -> dict:
     outputs = runner.outputs(last)
     del last
 
-    reduced, traced = None, None
+    reduced = None
     if args.trace:
         out, path = tracing.capture(runner.call, TRACE_DIR)
         traced = runner.counts(out)
         failed += runner.failed(traced)
         del out
-        reduced = trace_reduce.reduce(tracing.extract(path))
+        trace = tracing.extract(path)
+        reduced = trace_reduce.reduce(trace)
+        by_region = regions.per_call(runner, trace, traced)
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
 
     numbers = runner.check(outputs)
@@ -100,19 +120,24 @@ def run(args, cell, devices, entry_setup=None) -> dict:
 
     if args.trace:
         ctx = {"build_s": runner.build_s, "compile_s": runner.compile_s,
-               "calls": calls, "trace": reduced}
+               "calls": calls, "trace": reduced, "traced": traced,
+               "regions": by_region["region_ms"],
+               "spans": by_region["spans"], "jit_window": jit_window}
+        if kept is not None:
+            kept.update(ctx, regions=by_region, runner=runner,
+                        trace_path=path, cell=cell.name, seed=args.seed,
+                        setup_s=setup_s)
         metrics = {}
         for m in cell.per_layer:
-            value = loader.metric(m).read(ctx)
+            value = loader.metric(m, cell.root).read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:
-        # one rate under two names, so that farm cells and replica sweeps
-        # each have a bound of their own
+        # the rate under the cell's own name for it, so that each kind of
+        # cell has a bound of its own
         rate = window.rate(calls)
-        values = {"events_per_s": rate, "replica_events_per_s": rate,
-                  "setup_s": setup_s}
-        metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+        metrics = {m["name"]: {"value": setup_s if m["name"] == "setup_s"
+                               else rate, "unit": m["unit"]}
                    for m in cell.end_to_end}
     result = {"correct": bool(ok and not failed), "attempted": len(calls),
               "failed": failed, "metrics": metrics, "device": device}

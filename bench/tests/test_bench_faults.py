@@ -10,24 +10,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import loader, run
+from bench import run
 from bench.entries import farm, sweep
 
 SEED = 2**31 + 5
-
-
-def _farm_cell():
-    c = loader.cell("farm20k_c6.websrv_j100k")
-    return dataclasses.replace(
-        c, config=dict(c.config, sim=dict(c.config["sim"], n_servers=128)),
-        traffic=dict(c.traffic, jobs=1500, warm_events=640,
-                     call_events=240))
-
-
-def _sweep_cell():
-    c = loader.cell("caseB_20srv.fig5_websrv")
-    return dataclasses.replace(
-        c, config=dict(c.config, seeds_per_point=1, jobs_per_replica=40))
 
 
 def _run(cell, setup):
@@ -72,16 +58,6 @@ def _half_batch(r, good):
     out = sweep.montecarlo.run_replicas(r.cfg, first, r.tc)
     idx = jnp.arange(r.R) % half
     return jax.tree.map(lambda x: x[idx], out)
-
-
-@pytest.fixture(scope="module")
-def farm_cell():
-    return _farm_cell()
-
-
-@pytest.fixture(scope="module")
-def sweep_cell():
-    return _sweep_cell()
 
 
 def test_unbroken_farm_run_is_correct(farm_cell):

@@ -5,7 +5,6 @@ import json
 import pytest
 
 from bench import loader
-from bench.window import Call
 
 SPEC = json.loads((loader.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -19,14 +18,25 @@ def test_cell_found_by_name(name):
     assert cell.config == json.loads((loader.ROOT / conf["file"]).read_text())
     assert cell.traffic == json.loads(
         (loader.BENCH / "traffic" / f"{w['traffic']}.json").read_text())
-    assert set(cell.limits) >= {"placement_mismatch", "energy_gap"}
-    assert loader.module("entries", cell.traffic["entry"]).setup
+    assert cell.limits and all(isinstance(v, (int, float))
+                               for v in cell.limits.values())
+    assert loader.entry(cell).setup
     assert loader.module("references", cell.config["reference"]).simulate
-    rate = "events_per_s" if cell.traffic["entry"] == "farm" \
-        else "replica_events_per_s"
+    rate, = _rates(name)
     assert [m["name"] for m in cell.end_to_end] == [rate, "setup_s"]
     # every per-layer metric moves an end-to-end metric its cell reports
     assert {m["moves"] for m in cell.per_layer} <= {rate, "setup_s"}
+
+
+def _rates(cell: str) -> list:
+    """The end-to-end metrics whose workloads list holds the cell."""
+    return [m["name"] for m in SPEC["end_to_end"]
+            if cell in m.get("workloads", ())]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_each_cell_is_on_exactly_one_rates_list(name):
+    assert len(_rates(name)) == 1, _rates(name)
 
 
 @pytest.mark.parametrize("entry", SPEC["per_layer"],
@@ -36,29 +46,24 @@ def test_metric_reader_matches_its_entry(entry):
     assert callable(mod.read)
 
 
-def _context(entry: str, trace: bool) -> dict:
-    """What the harness hands a reader after a window of two calls."""
-    counts = {"events": 800, "steps": 100, "done": False}
-    if entry == "sweep":
-        counts = {"events": 3000, "steps": 150, "done": True,
-                  "replica_steps": [150, 120, 100],
-                  "replica_events": [1200, 1000, 800]}
-    tr = {"busy_s": 3.0, "window_s": 4.0} if trace else None
-    return {"build_s": 1.0, "compile_s": 2.0, "trace": tr,
-            "calls": [Call(0.0, 2.0, counts), Call(2.0, 4.0, counts)]}
+# the metrics of the traced call's regions, program spans and jit
+# counters, which a run has only with --trace 1
+TRACED = {m["name"] for m in SPEC["per_layer"]
+          if m["source"] == "device_trace"
+          or m["name"].split(".")[0] == "jit_traces_per_call"}
 
 
 @pytest.mark.parametrize("entry", SPEC["per_layer"],
                          ids=[m["name"] for m in SPEC["per_layer"]])
-def test_metric_reads_a_number_in_each_of_its_cells(entry):
+def test_metric_reads_a_number_in_each_of_its_cells(entry, reader_context):
     reader = loader.metric(entry)
     cells = entry.get("workloads", CELLS)
     for name in cells:
         kind = loader.cell(name).traffic["entry"]
-        value = reader.read(_context(kind, trace=True))
+        value = reader.read(reader_context(kind, trace=True))
         assert value is not None and value > 0, name
-    if entry["source"] == "device_trace":
-        assert reader.read(_context("farm", trace=False)) is None
+        if entry["name"] in TRACED:
+            assert reader.read(reader_context(kind, trace=False)) is None
 
 
 def test_metric_reader_disagreeing_with_entry_is_refused():

@@ -39,6 +39,7 @@ def test_reference_matches_the_program_on_a_small_farm():
     assert int(out.events) == n_ev
     ref = farm_des.simulate(sim, arr, svc, tau, max_events=n_ev)
     gaps = compare.gaps(program.outputs(out, len(arr)), ref)
+    assert tuple(gaps) == compare.NUMBERS
     ok, checks = compare.judge(gaps, cell.limits)
     assert ok, checks
     assert int((np.asarray(out.farm.wake_count)).sum()) > 0   # sleeps woke
@@ -76,7 +77,7 @@ def test_control_in_bfloat16_is_not_correct(seed):
 def test_histogram_fault_is_not_correct_at_the_cells_size(name):
     # the same jobs finish at the same times; only their bins are off
     cell = loader.cell(name)
-    entry = loader.module("entries", cell.traffic["entry"])
+    entry = loader.entry(cell)
     ok, checks = compare.judge(entry.control(cell, SEED), cell.limits)
     assert ok, checks
     numbers = entry.control(cell, SEED, alter=control.hist_shift)

@@ -94,6 +94,83 @@ def test_region_seconds_other_and_mixed_share():
     assert trace_reduce.reduce(tr)["busy_s"] == pytest.approx(r["busy_s"])
 
 
+class _Runner:
+    """A runner whose program is the hand-made HLO text."""
+
+    def program_text(self):
+        return HLO
+
+
+def _call_trace(extra_ops=(), modules=(("jit_run(7)", 2 * MS, 10 * MS),)):
+    """A traced call of the hand-made program: its ops, host and program
+    spans, and the module line."""
+    ops = {"/device:TPU:0": [
+        ("%while.4 = f32[8]{0} while(%x)", 2 * MS, 10 * MS),
+        ("%fusion.1 = f32[8]{0} fusion(%p)", 3 * MS, 3 * MS),
+        ("fusion.2", 6 * MS, 2 * MS),
+        ("copy.3", 8 * MS, 1 * MS),
+        ("fusion.1", 10 * MS, 4 * MS),         # runs past the call
+        *extra_ops]}
+    return {"device": ops, "modules": list(modules),
+            "host": [("call", 2 * MS, 10 * MS), ("dispatch", 2 * MS, MS),
+                     ("wait", 3 * MS, 9 * MS)],
+            "program": [("run_replicas.trace", 2 * MS, MS // 2),
+                        ("run_replicas.trace", 20 * MS, MS)]}  # after it
+
+
+def test_per_call_gives_reduce_per_loop_iteration():
+    # the one reduction of a traced call, for run.py and regions.py alike
+    _, table = regions.hlo_regions(HLO)
+    trace = _call_trace()
+    by = regions.per_call(_Runner(), trace, {"steps": 4})
+    r = regions.reduce(trace["device"], table, (2 * MS, 12 * MS))
+    assert r["unmapped"] == {}
+    assert by["module"] == "jit_run"
+    assert by["region_ms"] == pytest.approx(
+        {k: s * 1e3 / 4 for k, s in r["seconds"].items()})
+    assert by["region_ms"]["power"] == pytest.approx(5.0 / 4)
+    assert by["busy_ms"] == pytest.approx(r["busy_s"] * 1e3 / 4)
+    assert by["leaf_ms"] == pytest.approx(sum(by["region_ms"].values()))
+    assert by["mixed_ms"] == pytest.approx(r["mixed_s"] * 1e3 / 4)
+    assert by["spans"] == {"run_replicas.trace": pytest.approx(5e-4)}
+    assert by["call"] == (2 * MS, 12 * MS)
+
+
+def test_per_call_refuses_an_op_missing_from_the_program_text():
+    # an op of another program would land in no region without a sign
+    trace = _call_trace(extra_ops=[("fusion.99", 9 * MS, 1 * MS)])
+    _, table = regions.hlo_regions(HLO)
+    r = regions.reduce(trace["device"], table, (2 * MS, 12 * MS))
+    assert r["unmapped"] == {"fusion.99": pytest.approx(1e-3)}
+    assert r["leaf_s"] == pytest.approx(sum(r["seconds"].values()))
+    with pytest.raises(ValueError, match="fusion.99"):
+        regions.per_call(_Runner(), trace, {"steps": 4})
+
+
+@pytest.mark.parametrize("modules", [
+    [("jit_other(3)", 2 * MS, 10 * MS)],                     # another
+    [("jit_run(7)", 2 * MS, 9 * MS), ("jit_add(5)", 11 * MS, MS // 2)],
+    [],                                                      # none
+], ids=["another", "one_more", "none"])
+def test_per_call_refuses_a_call_that_ran_another_module(modules):
+    trace = _call_trace(modules=modules)
+    with pytest.raises(ValueError, match="modules"):
+        regions.per_call(_Runner(), trace, {"steps": 4})
+
+
+@pytest.mark.parametrize("cell", ["farm_cell", "sweep_cell"])
+def test_program_text_maps_to_the_engine_regions(cell, request):
+    # each entry's program text is what its calls run: its ops fall in
+    # the engine's regions
+    from bench import loader
+    c = request.getfixturevalue(cell)
+    runner = loader.entry(c).setup(c, 2**31 + 9)
+    module, table = regions.hlo_regions(runner.program_text())
+    assert module.startswith("jit_")
+    found = {r for r, _ in table.values()}
+    assert {"next_event", "admission", "drain_start", "telemetry"} <= found
+
+
 def test_real_compile_maps_each_instruction_to_its_scope():
     def f(x):
         with jax.named_scope("advance"):
@@ -137,12 +214,12 @@ def test_run_replicas_spans_nest_inside_the_dispatch_span(tmp_path):
     host = tracing.extract(path)["host"]
     assert [n for n, _, _ in host] == ["call", "dispatch", "wait"]
     (_, c0, cd), (_, d0, dd), _ = host
-    spans = regions.extract(path)["program"]
-    assert [n for n, _, _ in spans] == list(regions.PROGRAM_SPANS)
+    spans = tracing.extract(path)["program"]
+    assert [n for n, _, _ in spans] == list(tracing.PROGRAM_SPANS)
     end = d0
     for _, s, d in spans:
         assert end <= s and s + d <= d0 + dd      # in order, in dispatch
         end = s + d
     secs = regions.span_seconds(spans, (c0, c0 + cd))
-    assert list(secs) == list(regions.PROGRAM_SPANS)
+    assert list(secs) == list(tracing.PROGRAM_SPANS)
     assert all(v > 0 for v in secs.values())

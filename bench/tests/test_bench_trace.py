@@ -77,4 +77,5 @@ def test_extract_reads_the_host_spans_of_a_real_trace(tmp_path):
     assert [n for n, _, _ in tr["host"]] == ["call", "dispatch", "wait"]
     (call, c0, cd), (_, d0, _), (_, w0, wd) = tr["host"]
     assert c0 <= d0 < w0 and w0 + wd <= c0 + cd
+    assert tr["program"] == []        # the program wrote no spans
     assert out.shape == (64, 64)
